@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.search import Index, SearchSpec, backends, exact_search
 from repro.search import telemetry
 from repro.search import plan as planlib
@@ -409,6 +410,7 @@ import json, time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import make_mesh
 from repro.search import Index, SearchSpec, backends
 
 NDEV, M, N, D, REPEATS = @NDEV@, @M@, @N@, @D@, @REPEATS@
@@ -418,7 +420,7 @@ q = jnp.asarray(rng.normal(size=(M, D)), jnp.float32)
 index = Index.build(db, metric="mips", k=10, backend="xla",
                     recall_target=0.95)
 if NDEV > 1:
-    mesh = jax.make_mesh((NDEV,), ("model",))
+    mesh = make_mesh((NDEV,), ("model",))
     index = index.shard(mesh, db_axis="model")
 index.search(q).values.block_until_ready()  # warmup/compile
 backends.reset_dispatch_counts()
@@ -429,6 +431,7 @@ out.values.block_until_ready()
 wall = (time.perf_counter() - t0) / REPEATS
 print("@@SHARD@@" + json.dumps({
     "devices": NDEV,
+    "platform": jax.devices()[0].platform,
     "backend": "sharded" if NDEV > 1 else "xla",
     "qps": M / wall,
     "wall_s_per_search": wall,
@@ -442,7 +445,8 @@ def bench_shard(m, n, d, device_counts, repeats, emit):
     """Device-count scaling of the sharded backend + host-tier waves.
 
     QPS and the one-dispatch-per-batch contract vs fake device count
-    (each count is a subprocess — XLA fixes the device count at import),
+    (each count is a CPU subprocess — XLA fixes the device count at
+    import; its rows carry ``platform: "cpu"``),
     plus the host-RAM cold tier's segment-wave schedule and per-wave
     live-row occupancy on the default single device.
     """
@@ -455,6 +459,9 @@ def bench_shard(m, n, d, device_counts, repeats, emit):
                           ("REPEATS", repeats)):
             child = child.replace(f"@{name}@", str(val))
         env = dict(os.environ)
+        # The children simulate devices on the host CPU, never the
+        # accelerator: the parent already holds it.
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={ndev}"
         )
@@ -470,7 +477,7 @@ def bench_shard(m, n, d, device_counts, repeats, emit):
                     if l.startswith("@@SHARD@@"))
         row = json.loads(line[len("@@SHARD@@"):])
         rows.append(row)
-        emit(f"shard,M={m},N={n},D={d},devices={ndev}: "
+        emit(f"shard,M={m},N={n},D={d},{row['platform']} devices={ndev}: "
              f"{row['qps']:.0f} qps "
              f"({row['dispatches_per_search']:.0f} dispatch)")
 
@@ -512,6 +519,7 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_search.json")
     ap.add_argument("--repeats", type=int, default=0, help="0 = auto")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         grid, bks, mets, qb = SMOKE_GRID, ("xla",), ("mips",), SMOKE_QUERY_BLOCK

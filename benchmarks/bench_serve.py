@@ -54,6 +54,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.search import Index, SearchSpec, SearchServer, ServeConfig, backends
 from repro.search import telemetry
 from repro.search.faults import FaultInjector, InjectedFault
@@ -536,6 +537,7 @@ def main() -> None:
     ap.add_argument("--duration", type=float, default=2.0,
                     help="seconds per poisson load point")
     args = ap.parse_args()
+    enable_compile_cache()
 
     index = _build_index()
     results = []

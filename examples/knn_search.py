@@ -18,6 +18,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.data.pipeline import make_vector_dataset  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.retrieval.datastore import KNNDatastore, knn_lm_logits  # noqa: E402
 from repro.search import Index, exact_search  # noqa: E402
 
@@ -30,7 +31,7 @@ def recall(a, e):
 
 
 def main():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     print(f"mesh: {dict(mesh.shape)} over {len(jax.devices())} devices")
 
     # Database + held-out queries split from one draw: clustered indexes

@@ -158,19 +158,28 @@ def _load_db_tile(x_ref, q_dtype, int4_packed: bool):
     nibbles per byte; unpack them here (arithmetic shifts sign-extend) so
     only the halved byte count ever crossed the memory wall.  Byte j holds
     logical column 2j in its low nibble and 2j+1 in its high nibble —
-    matching ``quant.pack_int4_rows``.
+    matching ``quant.pack_int4_rows``.  The tile is unpacked as
+    ``[all low nibbles | all high nibbles]`` (two lane-aligned halves, no
+    interleave) and the query columns are permuted to match outside the
+    kernel (:func:`_int4_query_order`), so the dot product is unchanged.
     """
     x = x_ref[...]
     if int4_packed:
         xb = x.astype(jnp.int32)
         lo = (xb << 28) >> 28
         hi = xb >> 4
-        x = jnp.stack([lo, hi], axis=-1).reshape(x.shape[0], -1)
+        x = jnp.concatenate([lo, hi], axis=1)
     if x.dtype != q_dtype:
         # Reduced-precision storage tier: dequantize the tile in VMEM
         # before it hits the MXU (per-row scales apply to the scores).
         x = x.astype(q_dtype)
     return x
+
+
+def _int4_query_order(queries: jnp.ndarray) -> jnp.ndarray:
+    """Query columns reordered ``[even | odd]`` to match the unpacked
+    int4 tile of :func:`_load_db_tile` (O(M·D), outside the kernel)."""
+    return jnp.concatenate([queries[:, 0::2], queries[:, 1::2]], axis=1)
 
 
 def _tile_winners(q_ref, x_ref, scale_ref, bias_ref,
@@ -182,11 +191,16 @@ def _tile_winners(q_ref, x_ref, scale_ref, bias_ref,
 
     q = q_ref[...]
     x = _load_db_tile(x_ref, q.dtype, int4_packed)
-    # MXU: one (block_m, d) x (d, block_n) matmul, f32 accumulation.
+    # MXU: one (block_m, d) x (d, block_n) matmul, f32 accumulation.  The
+    # f32 tier's scores are its results, so its dot runs at full f32
+    # precision; a quantized tier's scan only ranks candidates for the
+    # exact rescore and keeps the default.
+    exact = x_ref.dtype == jnp.float32 and q.dtype == jnp.float32
     scores = jax.lax.dot_general(
         q,
         x,
         (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST if exact else None,
         preferred_element_type=jnp.float32,
     )
     if scale_ref is not None:
@@ -368,14 +382,22 @@ def partial_reduce_pallas(
     may be a reduced-precision storage tier (bf16/int8/int4); ``scale`` is
     the scaled tiers' per-row dequantization scale, applied to the score
     tile in VMEM.
+
+    The kernel writes its bin winners as (n // block_n, m, bins_per_block)
+    — one (block_m, bins_per_block) block per grid step, whose last dim is
+    the array's full last dim, as the TPU block rule requires however few
+    bins a tile holds — and the (m, num_bins) row-major result is
+    assembled outside the kernel.
     """
     _validate_tiling(queries, database, block_m=block_m, block_n=block_n,
                      bin_size=bin_size, int4_packed=int4_packed)
+    if int4_packed:
+        queries = _int4_query_order(queries)
     m, d = queries.shape
     n, w = database.shape
-    num_bins = n // bin_size
+    num_blocks = n // block_n
     bins_per_block = block_n // bin_size
-    grid = (m // block_m, n // block_n)
+    grid = (m // block_m, num_blocks)
 
     in_specs = [
         pl.BlockSpec((block_m, d), lambda i, j: (i, 0)),
@@ -391,20 +413,25 @@ def partial_reduce_pallas(
         # scale rides the same (1, block_n) tiling as the bias row.
         in_specs.insert(2, pl.BlockSpec((1, block_n), lambda i, j: (0, j)))
         operands = (queries, database, scale, bias)
-    return pl.pallas_call(
+    out_block = pl.BlockSpec((pl.Squeezed(), block_m, bins_per_block),
+                             lambda i, j: (j, i, 0))
+    vals, idxs = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block_m, bins_per_block), lambda i, j: (i, j)),
-            pl.BlockSpec((block_m, bins_per_block), lambda i, j: (i, j)),
-        ],
+        out_specs=[out_block, out_block],
         out_shape=[
-            jax.ShapeDtypeStruct((m, num_bins), jnp.float32),
-            jax.ShapeDtypeStruct((m, num_bins), jnp.int32),
+            jax.ShapeDtypeStruct((num_blocks, m, bins_per_block),
+                                 jnp.float32),
+            jax.ShapeDtypeStruct((num_blocks, m, bins_per_block),
+                                 jnp.int32),
         ],
         interpret=interpret,
     )(*operands)
+    return (
+        vals.transpose(1, 0, 2).reshape(m, num_blocks * bins_per_block),
+        idxs.transpose(1, 0, 2).reshape(m, num_blocks * bins_per_block),
+    )
 
 
 @functools.partial(
@@ -439,6 +466,8 @@ def partial_reduce_fused_pallas(
                      bin_size=bin_size, int4_packed=int4_packed)
     if k_scan <= 0:
         raise ValueError(f"k_scan must be positive, got {k_scan}")
+    if int4_packed:
+        queries = _int4_query_order(queries)
     m, d = queries.shape
     n, w = database.shape
     grid = (m // block_m, n // block_n)

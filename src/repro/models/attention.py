@@ -395,9 +395,7 @@ def _knn_decode_attention_cp(q, keys, values, valid, *, k, recall_target,
         return jnp.einsum("bhk,bhkd->bhd", probs, top_v)
 
     cp_spec = tuple(cp_axes) if len(cp_axes) > 1 else cp_axes[0]
-    from repro.parallel.sharding import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -407,6 +405,7 @@ def _knn_decode_attention_cp(q, keys, values, valid, *, k, recall_target,
             P(cp_spec),
         ),
         out_specs=P(),
+        check_vma=False,
     )
     return fn(q, keys, values, valid)
 

@@ -22,28 +22,8 @@ __all__ = [
     "activation_rules",
     "use_mesh",
     "current_mesh",
-    "shard_map_compat",
 ]
 
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions, replication checking disabled.
-
-    shard_map moved out of jax.experimental (and ``check_rep`` became
-    ``check_vma``) around jax 0.6.  Checking is off because our collectives
-    (all_gather over the reduced axis) produce replication the static
-    checker cannot infer.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
 
 # logical axis -> mesh axes (None = replicated).  ("pod","data") only ever
 # shards batch-like axes; "model" shards head/ffn/expert/vocab axes.

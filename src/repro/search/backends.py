@@ -41,10 +41,11 @@ from repro.kernels.partial_reduce import (
     partial_reduce_packed,
     partial_reduce_pallas,
 )
-from repro.parallel.sharding import shard_map_compat
 from repro.search.metrics import get_metric
+from repro.search.plan import _plan_query_block
 from repro.search import telemetry
 from repro.search.stages import (
+    EXACT,
     MASK_VALUE,
     finalize_values,
     merge_topk,
@@ -707,6 +708,21 @@ def db_shard_count(mesh: Mesh, db_axis) -> int:
     return count
 
 
+def _map_query_blocks(fn, q, rows: int):
+    """``fn`` over ``q`` in ``rows``-row blocks, as one ``lax.map``.
+
+    Queries are independent, so the result equals ``fn(q)``; the padded
+    tail rows are computed and dropped.
+    """
+    m = q.shape[0]
+    if m <= rows:
+        return fn(q)
+    blocks = -(-m // rows)
+    padded = jnp.pad(q, ((0, blocks * rows - m), (0, 0)))
+    outs = jax.lax.map(fn, padded.reshape(blocks, rows, q.shape[1]))
+    return tuple(o.reshape(blocks * rows, *o.shape[2:])[:m] for o in outs)
+
+
 def make_sharded_search_fn(
     mesh: Mesh,
     *,
@@ -849,7 +865,8 @@ def make_sharded_search_fn(
                         idxs = jnp.take_along_axis(idxs, sel, axis=-1)
                     lsel = jnp.take_along_axis(lidc, pos, axis=-1)
                     exact = (
-                        jnp.einsum("md,mld->ml", q, rs_db[lsel])
+                        jnp.einsum("md,mld->ml", q, rs_db[lsel],
+                                   precision=EXACT)
                         + rs_bias[lsel]
                     )
                     vals = jnp.where(
@@ -857,31 +874,41 @@ def make_sharded_search_fn(
                     )
                 # idxs are global user ids already — no offset to add.
             else:
-                scores = score_rows(q, db, b, sc)
-                plan = plan_bins(
-                    n_local, min(scan_k, n_local), recall_target,
-                    reduction_input_size_override=global_n,
-                )
-                vals, idxs = partial_reduce_with_plan(
-                    scores, plan, mode="max"
-                )
-                if with_rescore:
-                    # Cut the shard's bin winners to its k_scan best by
-                    # quantized score, then exact-rescore only those — the
-                    # all-gather then carries exact scores (and ~k_scan
-                    # rows per shard instead of L).
-                    k_cut = min(scan_k, vals.shape[-1])
-                    if k_cut < vals.shape[-1]:
-                        vals, sel = jax.lax.top_k(vals, k_cut)
-                        idxs = jnp.take_along_axis(idxs, sel, axis=-1)
-                    rows = rs_db[idxs]
-                    exact = (
-                        jnp.einsum("md,mld->ml", q, rows) + rs_bias[idxs]
+                def dense_scan(qs):
+                    scores = score_rows(qs, db, b, sc)
+                    plan = plan_bins(
+                        n_local, min(scan_k, n_local), recall_target,
+                        reduction_input_size_override=global_n,
                     )
-                    vals = jnp.where(
-                        vals > MASK_VALUE * 0.5, exact, MASK_VALUE
+                    vals, idxs = partial_reduce_with_plan(
+                        scores, plan, mode="max"
                     )
-                idxs = idxs + offset
+                    if with_rescore:
+                        # Cut the shard's bin winners to its k_scan best by
+                        # quantized score, then exact-rescore only those —
+                        # the all-gather then carries exact scores (and
+                        # ~k_scan rows per shard instead of L).
+                        k_cut = min(scan_k, vals.shape[-1])
+                        if k_cut < vals.shape[-1]:
+                            vals, sel = jax.lax.top_k(vals, k_cut)
+                            idxs = jnp.take_along_axis(idxs, sel, axis=-1)
+                        rows = rs_db[idxs]
+                        exact = (
+                            jnp.einsum("md,mld->ml", qs, rows,
+                                       precision=EXACT)
+                            + rs_bias[idxs]
+                        )
+                        vals = jnp.where(
+                            vals > MASK_VALUE * 0.5, exact, MASK_VALUE
+                        )
+                    return vals, idxs + offset
+
+                # The shard's dense scan materializes a (queries, n_local)
+                # score tile: bound it exactly as the single-device xla
+                # path bounds its (query_block, N) tile.
+                vals, idxs = _map_query_blocks(
+                    dense_scan, q, _plan_query_block(n_local, "xla")
+                )
             # The only cross-device traffic of the whole search: O(k_scan)
             # (value, global id) winners per shard, merged replicated.
             vals = jax.lax.all_gather(vals, db_axes, axis=-1, tiled=True)
@@ -889,11 +916,14 @@ def make_sharded_search_fn(
             top_v, top_i = merge_topk(vals, idxs, k, use_bitonic=use_bitonic)
             return finalize_values(top_v, m_obj.negate_output), top_i
 
-        fn = shard_map_compat(
+        # Replication checking is off: the all-gather over the db axes
+        # replicates the merged winners in a way the checker cannot infer.
+        fn = jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=(P(batch_axis, None), P(batch_axis, None)),
+            check_vma=False,
         )
         return fn(*args)
 

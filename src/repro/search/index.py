@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.binning import BinPlan, plan_bins, round_up
+from repro.launch.mesh import auto_axes
 from repro.search import backends, packed as packedlib, plan as planlib
 from repro.search import cluster as clusterlib
 from repro.search import faults as faultslib
@@ -305,6 +306,14 @@ class Index:
         if rate is None:
             return float(decomp["expected_recall"])
         return float(decomp["collision_term"] * (1.0 - rate))
+
+    @property
+    def interpret(self) -> bool:
+        """Whether Pallas kernels run in interpret mode: as passed at
+        build, else exactly when the default backend is not a TPU."""
+        if self._interpret is None:
+            return jax.default_backend() != "tpu"
+        return self._interpret
 
     def _cluster_plan_in_effect(self):
         """The ClusterPlan the live search path actually prunes with.
@@ -913,9 +922,7 @@ class Index:
                 )
             return fn
         if backend == "pallas":
-            interpret = self._interpret
-            if interpret is None:
-                interpret = jax.default_backend() != "tpu"
+            interpret = self.interpret
             n, bin_size, block_n = pk.n, pk.bin_size, pk.block_n
             fused = spec.fused_select_enabled
             int4_packed = spec.storage == "int4"
@@ -1210,7 +1217,8 @@ class Index:
         batch_axis: Optional[str] = None,
     ) -> "Index":
         """Return a mesh-sharded copy: rows P(db_axis, None), queries
-        optionally sharded over ``batch_axis``.
+        optionally sharded over ``batch_axis``.  A mesh with Explicit axis
+        types is taken as the same devices with Auto axes.
 
         ``db_axis`` may be one mesh axis name or a *tuple* of names — the
         tuple form folds a pod-shaped (multi-host-shaped) mesh into one
@@ -1228,6 +1236,7 @@ class Index:
                 "streams segments through a single device's HBM; rebuild "
                 "with residency='hbm' first"
             )
+        mesh = auto_axes(mesh)
         n_shards = backends.db_shard_count(mesh, db_axis)
         cap = round_up(self.capacity, n_shards)
         db, live = self._db, self._live

@@ -138,13 +138,25 @@ def _dtype_bytes(dtype: Optional[str]) -> int:
     return _DTYPE_BYTES.get(str(dtype), 4)
 
 
+# TPU ``device_kind`` (lower-cased) -> HARDWARE profile.  A TPU whose kind
+# is not here has no profile: planning against another chip's walls would
+# mis-tile every kernel, so detect_device raises instead of guessing.
+TPU_KINDS = {
+    "tpu v3": "tpu_v3",
+    "tpu v4": "tpu_v4",
+    "tpu v5 lite": "tpu_v5e",
+    "tpu v5e": "tpu_v5e",
+}
+
+
 def detect_device(name: Optional[str] = None) -> str:
     """Resolve a device-profile name against ``repro.core.roofline.HARDWARE``.
 
-    ``None`` auto-detects from the live JAX backend: TPUs map onto the
-    closest Table-1 profile by device kind, GPUs onto A100, anything else
-    onto the ``"cpu"`` host profile (whose tile budget mirrors the TPU so
-    host-planned layouts match device-planned ones).
+    ``None`` auto-detects from the live JAX backend: TPUs map onto their
+    profile by device kind (``TPU_KINDS``; an unknown kind raises), GPUs
+    onto A100, anything else onto the ``"cpu"`` host profile (whose tile
+    budget mirrors the TPU so host-planned layouts match device-planned
+    ones).
 
     >>> detect_device("tpu_v4")
     'tpu_v4'
@@ -159,18 +171,14 @@ def detect_device(name: Optional[str] = None) -> str:
 
     backend = jax.default_backend()
     if backend == "tpu":
-        kind = jax.devices()[0].device_kind.lower()
-        # device_kind strings look like "tpu v3", "tpu v4", "tpu v5 lite".
-        # Only v5e/v5-lite maps to the v5e profile; other v5 variants
-        # (e.g. v5p) have no profile yet and take the generic v4 default
-        # rather than v5e's much lower roofline.
-        if "v5e" in kind or "v5 lite" in kind or "v5lite" in kind:
-            return "tpu_v5e"
-        if "v4" in kind:
-            return "tpu_v4"
-        if "v3" in kind:
-            return "tpu_v3"
-        return "tpu_v4"
+        kind = jax.devices()[0].device_kind
+        try:
+            return TPU_KINDS[kind.lower()]
+        except KeyError:
+            raise ValueError(
+                f"no hardware profile for TPU kind {kind!r}; known kinds: "
+                f"{sorted(TPU_KINDS)} -> profiles {sorted(HARDWARE)}"
+            ) from None
     if backend == "gpu":
         return "a100"
     return "cpu"
@@ -358,11 +366,28 @@ def _vmem_need(block_m: int, block_n: int, d_pad: int, dtype_bytes: int,
     kernel's top-k carry — a persistent (block_m, k_scan) f32-value +
     int32-index scratch pair that lives in VMEM across the whole database
     stream, so it is budgeted alongside the per-step tiles.
+
+    A stored tier narrower than the compute dtype is dequantized in VMEM
+    (``kernels.partial_reduce._load_db_tile``): that costs a compute-dtype
+    copy of the database tile, and int4 first unpacks its nibbles into
+    int32 low/high halves — block_n x d_pad int32 in all.  The f32 tier's
+    dot runs at full precision, for which the TPU compiler splits both
+    operand tiles into bf16 high and low halves (2.2 MiB more at the
+    sift1m tiles block_m 512, block_n 4096, where 17 MiB broke the 16 MiB
+    scoped limit on a v5e).
     """
     if db_bytes is None:
         db_bytes = dtype_bytes
+    unpack = 0.0
+    if db_bytes != dtype_bytes:
+        unpack += block_n * d_pad * dtype_bytes     # dequantized tile
+    if db_bytes == 0.5:
+        unpack += block_n * d_pad * 4               # int32 nibble halves
+    if db_bytes == dtype_bytes == 4:
+        unpack += (block_m + block_n) * d_pad * 4   # bf16 hi/lo splits
     return (
         d_pad * (block_m * dtype_bytes + block_n * db_bytes)  # operand tiles
+        + unpack
         + block_m * block_n * 4                     # score tile (f32)
         + 2 * block_m * max(1, block_n // bin_size) * 4  # winners (val+idx)
         + 2 * block_m * k_scan * 4                  # fused top-k carry

@@ -102,17 +102,13 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.search import cluster as clusterlib
 from repro.search import faults as faultslib
 from repro.search import telemetry as telemetrylib
 from repro.search.index import Index, SearchResult
 from repro.search.plan import plan_buckets
-
-try:  # dispatch-path profiler hook; absent on stripped-down jax builds
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover - depends on the jax build
-    _TraceAnnotation = None
 
 __all__ = [
     "DeadlineExceeded",
@@ -843,7 +839,8 @@ class SearchServer:
                 # restarted worker on a gate its dead self never took.
                 self._fire("serve.dispatch")
                 with self._dispatch_gate:
-                    with self._profile_span(f"serve.dispatch[{bucket}]"):
+                    # Names the dispatch in device profiles.
+                    with TraceAnnotation(f"serve.dispatch[{bucket}]"):
                         result = self.index.search(q)  # ONE dispatch
                 break
             except faultslib.WorkerDeath:
@@ -1153,13 +1150,6 @@ class SearchServer:
             yield
 
     # -- observability -------------------------------------------------------
-
-    def _profile_span(self, name: str):
-        """``jax.profiler.TraceAnnotation`` around the coalesced dispatch
-        (shows up in device profiles); no-op when the profiler is absent."""
-        if _TraceAnnotation is not None:
-            return _TraceAnnotation(name)
-        return contextlib.nullcontext()
 
     def _store_trace(self, trace: telemetrylib.RequestTrace) -> None:
         """Push a completed trace into the bounded ring buffer (deque

@@ -71,6 +71,11 @@ __all__ = [
 # propagation while still losing every comparison against real scores.
 MASK_VALUE = float(np.finfo(np.float32).min)
 
+# Dots whose scores are returned (the f32 tier's scan, every exact rescore)
+# run at full f32 precision: the TPU's default rounds f32 operands to bf16.
+# A narrower tier's scan only ranks candidates and keeps the default.
+EXACT = jax.lax.Precision.HIGHEST
+
 Array = jnp.ndarray
 
 
@@ -115,7 +120,9 @@ def score_rows(
     adding it *after* the scale keeps quantized scan scores internally
     consistent (the bias is computed from the stored values).
     """
-    scores = jnp.einsum("ik,jk->ij", q, database)
+    exact = database.dtype == jnp.float32
+    scores = jnp.einsum("ik,jk->ij", q, database,
+                        precision=EXACT if exact else None)
     if scale is not None:
         scores = scores * scale[None, :]
     if row_bias is not None:
@@ -201,7 +208,7 @@ def rescore_candidates(q, scan_vals, idxs, rescore_db, rescore_bias, k,
         scan_vals, sel = jax.lax.top_k(scan_vals, k_scan)
         idxs = jnp.take_along_axis(idxs, sel, axis=-1)
     rows = rescore_db[idxs]                           # (m, k_scan, d) gather
-    exact = jnp.einsum("md,mld->ml", q, rows)
+    exact = jnp.einsum("md,mld->ml", q, rows, precision=EXACT)
     exact = exact + rescore_bias[idxs]
     exact = jnp.where(scan_vals > MASK_VALUE * 0.5, exact, MASK_VALUE)
     return exact_rescoring(exact, idxs, k, mode="max", use_bitonic=use_bitonic)

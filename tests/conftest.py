@@ -11,6 +11,13 @@ results instead of grepping stdout.
 ``device_grid`` parametrizes a test over pod-ish grid sizes; anything past
 8 devices is ``@slow``-marked (compile times grow superlinearly with the
 fake-device count) and excluded from the fast CI tier's ``-m "not slow"``.
+
+``CPU_DOT_FLAGS`` keeps the CPU backend's matmuls on one code path.  By
+default XLA:CPU hands some dot shapes to YNNPACK, whose summation order
+differs from its own emitter's, so the same row can score a last-ulp
+differently inside differently-shaped tiles.  The layout- and tile-parity
+tests compare results bit for bit, so every test process (this one and
+the fake-device children) runs with YNNPACK fusion off.
 """
 import os
 import pickle
@@ -22,9 +29,17 @@ import pytest
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+CPU_DOT_FLAGS = "--xla_cpu_experimental_ynn_fusion_type="
+# Read when the CPU backend starts, which is after this module is imported.
+os.environ["XLA_FLAGS"] = " ".join(
+    filter(None, [os.environ.get("XLA_FLAGS"), CPU_DOT_FLAGS])
+)
+
 _PRELUDE = """\
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count={n} {cpu_dot_flags}"
+)
 import pickle as _pickle
 
 
@@ -50,7 +65,9 @@ class FakeDeviceRunner:
         env.pop("XLA_FLAGS", None)
         with tempfile.TemporaryDirectory() as tmp:
             path = os.path.join(tmp, "result.pkl")
-            script = _PRELUDE.format(n=n, path=path) + source
+            script = _PRELUDE.format(
+                n=n, path=path, cpu_dot_flags=CPU_DOT_FLAGS
+            ) + source
             out = subprocess.run(
                 [sys.executable, "-c", script], env=env,
                 capture_output=True, text=True, timeout=timeout,

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.search import (
     ClusterPlan,
     Index,
@@ -251,7 +252,7 @@ def test_cluster_composes_with_quantized_storage(data, storage):
 
 def test_sharded_cluster_search_single_shard(data):
     db, q = data
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     index = Index.build(db, metric="l2", k=K, backend="xla").shard(
         mesh, db_axis="model"
     )
@@ -270,7 +271,7 @@ def test_sharded_cluster_quant_and_f32_operand_binding(data, storage):
     signature; both storage tiers must bind them correctly (a positional
     mix-up would feed centroids where scales belong)."""
     db, q = data
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     index = Index.build(db, metric="l2", k=K, backend="xla",
                         storage=storage).shard(mesh, db_axis="model")
     _, idxs = index.search(q)

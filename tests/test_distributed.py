@@ -6,11 +6,12 @@ the main pytest process keeps a single CPU device.
 _SCRIPT = r"""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import sharded_mips, sharded_l2nns
 from repro.retrieval.datastore import KNNDatastore, knn_lm_logits
 from repro.search import Index, exact_search
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 key = jax.random.PRNGKey(0)
 q = jax.random.normal(key, (16, 64))
 db = jax.random.normal(jax.random.PRNGKey(1), (4096, 64))

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.search import Index, SearchSpec, backends
 from repro.search.backends import DISPATCH_COUNTS, TRACE_COUNTS
 from repro.search.packed import PACK_EVENTS, reset_pack_events
@@ -394,7 +395,7 @@ def test_delete_patches_bias_only_and_never_syncs(data):
 
 def test_shard_reuses_packed_layout(data):
     q, db = data
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     index = Index.build(db, metric="cosine", k=K)
     reset_pack_events()
     sharded = index.shard(mesh, db_axis="model")
@@ -429,7 +430,7 @@ def test_stream_matches_per_block_loop(data, backend, m):
 
 def test_stream_matches_loop_sharded(data):
     _, db = data
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     queries = jax.random.normal(jax.random.PRNGKey(5), (100, 32))
     stream = (
         Index.build(db[:1024], k=K, query_block=16)
@@ -453,9 +454,10 @@ def test_stream_matches_loop_sharded(data):
 
 _MULTIDEVICE_SCRIPT = r"""
 import jax, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.search import Index, SearchSpec, exact_search
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 db = jax.random.normal(jax.random.PRNGKey(1), (4096, 64))
 q = jax.random.normal(jax.random.PRNGKey(0), (128, 64))
 

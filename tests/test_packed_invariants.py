@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import make_mesh
 from repro.search import Index, SearchSpec, fuse_bias, get_metric
 from repro.search.packed import pack_state
 
@@ -480,7 +481,7 @@ def _restore_parity(index, queries, tmp_path, *, mesh_axis=None):
     reset_pack_events()
     restored = Index.restore(path)
     if mesh_axis is not None:  # snapshots land unmeshed; re-shard explicitly
-        restored = restored.shard(jax.make_mesh((1,), (mesh_axis,)),
+        restored = restored.shard(make_mesh((1,), (mesh_axis,)),
                                   db_axis=mesh_axis)
     got = restored.search(queries)
     np.testing.assert_array_equal(
@@ -531,7 +532,7 @@ def test_restore_parity_clustered(storage, tmp_path):
 
 
 def test_restore_parity_sharded_single_device(tmp_path):
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     db = _db(14, 512)
     index = Index.build(db, metric="mips", k=8).shard(mesh, db_axis="model")
     q = jax.random.normal(jax.random.PRNGKey(15), (8, D))
@@ -542,7 +543,7 @@ def test_restore_parity_sharded_2d(tmp_path):
     """A 2-D (query x database) sharded index snapshots its full logical
     arrays; restore lands unmeshed and re-sharding onto a 2-D mesh brings
     back bit-identical results with no re-pack."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     db = _db(16, 512)
     index = Index.build(db, metric="l2", k=8).shard(
         mesh, db_axis=("data", "model")
@@ -553,7 +554,7 @@ def test_restore_parity_sharded_2d(tmp_path):
     index.save(path)
     reset_pack_events()
     restored = Index.restore(path).shard(
-        jax.make_mesh((1, 1), ("data", "model")),
+        make_mesh((1, 1), ("data", "model")),
         db_axis=("data", "model"), batch_axis=None,
     )
     got = restored.search(q)

@@ -105,10 +105,14 @@ def test_overrides_pin_choices():
 
 def test_block_m_escalates_off_the_memory_wall():
     """Paper-scale L2 on TPU v4: the planner must not leave the kernel
-    memory-bound when a larger query tile fixes it (Fig. 2 as a decision)."""
+    memory-bound when a larger query tile fixes it (Fig. 2 as a decision).
+
+    At recall 0.99 the bins (and so block_n) are 1024 wide, and a 512-row
+    query tile fits VMEM; at 0.95 a bin fills a 4096-row block_n and the
+    full-precision f32 tile at 512 rows would not (``_vmem_need``)."""
     p = plan_search(
         n=1_000_000, d=128, k=10, m=10_000, metric="l2", device="tpu_v4",
-        backend="pallas",
+        backend="pallas", recall_target=0.99,
     )
     assert p.bottleneck != "memory"
     assert p.block_m > 256  # escalated beyond the legacy anchor
@@ -120,6 +124,26 @@ def test_block_m_escalates_off_the_memory_wall():
 def test_device_detection_resolves():
     assert detect_device() in HARDWARE  # live backend, whatever it is
     assert detect_device("cpu") == "cpu"
+
+
+@pytest.mark.parametrize("kind,profile", [
+    ("TPU v5 lite", "tpu_v5e"),
+    ("TPU v4", "tpu_v4"),
+    ("TPU v7x", None),
+])
+def test_device_detection_by_tpu_kind(monkeypatch, kind, profile):
+    """A TPU maps onto its profile by ``device_kind``; a kind with no
+    profile raises (naming it) instead of planning against another chip."""
+    class StubDevice:
+        device_kind = kind
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [StubDevice()])
+    if profile is not None:
+        assert detect_device() == profile
+    else:
+        with pytest.raises(ValueError, match="TPU v7x"):
+            detect_device()
 
 
 # --- Index integration ------------------------------------------------------

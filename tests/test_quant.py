@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.search import (
     Index,
     SearchSpec,
@@ -160,7 +161,7 @@ def test_f32_storage_is_bit_identical(data, backend, metric):
 
 def test_f32_storage_is_bit_identical_sharded(data):
     q, db = data
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     plain = Index.build(db, k=K).shard(mesh, db_axis="model").search(q)
     tiered = (
         Index.build(db, k=K, storage="f32")
@@ -196,7 +197,7 @@ def test_quantized_search_recall_floor(data, backend, storage, metric):
 @pytest.mark.parametrize("storage", ["bf16", "int8"])
 def test_quantized_search_sharded(data, storage):
     q, db = data
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     index = Index.build(db, metric="l2", k=K, storage=storage).shard(
         mesh, db_axis="model"
     )

@@ -31,6 +31,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.search import Index, exact_search
 
 N = 2048
@@ -256,7 +257,7 @@ def test_recall_guarantee_sharded_global_accounting():
     """Paper §7: on the sharded backend the bin budget is split across
     shards but recall is accounted against the *global* N — the guarantee
     must survive that redistribution."""
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     samples = []
     expected = None
     root = jax.random.PRNGKey(7)
@@ -289,7 +290,7 @@ def test_recall_guarantee_sharded_2d_global_accounting():
     the 1-D §7 argument — the measured recall must clear both the target
     and the planner's analytic expectation, and the plan must price the
     per-shard scan (not the global one) plus the ICI gather."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     samples = []
     expected = None
     root = jax.random.PRNGKey(21)

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.search import (
     Index,
     SearchSpec,
@@ -43,7 +44,7 @@ def data():
 @pytest.fixture(scope="module")
 def mesh1():
     """Single-device mesh: exercises the sharded code path in-process."""
-    return jax.make_mesh((1,), ("model",))
+    return make_mesh((1,), ("model",))
 
 
 def _build(db, metric, backend, mesh=None, **kw):
@@ -68,6 +69,70 @@ def test_backend_meets_recall_target(data, mesh1, metric, backend):
     _, exact = exact_search(q, db, K, metric=metric)
     assert vals.shape == idxs.shape == (64, K)
     assert _recall(idxs, exact) >= index.expected_recall - 0.05
+
+
+def test_sharded_scan_bounds_its_score_tile(mesh1):
+    """A shard scans its rows in query sub-blocks sized like the xla
+    path's query block, so its (queries, n_local) score tile stays under
+    the planner's budget; results equal searches too small to sub-block."""
+    from repro.search.plan import _plan_query_block
+
+    n, m, d = 16384, 2500, 16
+    db = jax.random.normal(jax.random.PRNGKey(2), (n, d))
+    q = jax.random.normal(jax.random.PRNGKey(3), (m, d))
+    assert 1000 <= _plan_query_block(n, "xla") < m  # sub-blocking engages
+    # Planned for the sharded backend, as the datastore builds it: the
+    # query block stays 4096, so the sub-blocking happens inside the shard.
+    index = Index.build(db, metric="l2", k=K, backend="sharded",
+                        cluster="off").shard(mesh1, db_axis="model")
+    assert index.spec.query_block >= m
+    whole = index.search(q)
+    parts = [index.search(q[i:i + 1000]) for i in range(0, m, 1000)]
+    for got, want in zip(whole, zip(*parts)):
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.concatenate(want))
+
+
+_EXPLICIT_MESH_SCRIPT = r"""
+import jax, numpy as np
+from jax.sharding import AxisType, Mesh
+from repro.launch.mesh import make_mesh
+from repro.search import Index
+
+auto = make_mesh((2, 4), ("data", "model"))
+explicit = Mesh(auto.devices, auto.axis_names,
+                axis_types=(AxisType.Explicit,) * 2)
+db = jax.random.normal(jax.random.PRNGKey(1), (4096, 64))
+q = jax.random.normal(jax.random.PRNGKey(0), (128, 64))
+new = jax.random.normal(jax.random.PRNGKey(2), (100, 64))
+indexes = [Index.build(db, metric="l2", k=10, query_block=32)
+           .shard(mesh, db_axis="model", batch_axis="data")
+           for mesh in (explicit, auto)]
+
+
+def same():
+    got, want = (ix.search(q) for ix in indexes)
+    return all(np.array_equal(np.asarray(g), np.asarray(w))
+               for g, w in zip(got, want))
+
+
+checks = [same()]  # 128 queries streamed in blocks of 32
+for ix in indexes:
+    ix.add(new)
+checks.append(same())
+for ix in indexes:
+    ix.delete(np.arange(10))
+checks.append(same())
+publish({"same": checks})
+"""
+
+
+def test_shard_takes_explicit_axis_mesh_as_auto(fake_devices):
+    """A mesh with Explicit axes (``jax.make_mesh``'s default) reaches
+    ``Index.shard`` as the same devices with Auto axes: on 8 fake devices
+    the streamed search, ``add`` and ``delete`` raise no sharding error and
+    match an Auto mesh bit for bit."""
+    assert fake_devices(_EXPLICIT_MESH_SCRIPT, n=8)["same"] == [True] * 3
 
 
 @pytest.mark.parametrize("metric", METRICS)

@@ -366,7 +366,8 @@ def test_datastore_lookup_via_server_matches_direct():
     assert ds.stats()["server"]["batches"] == 1
     # frequent updates keep working with a server attached (extend/forget
     # take the server's mutation gate) and are immediately visible
-    new_keys = _queries(81, 4)
+    # long keys, so under MIPS each new key is its own exact top-1
+    new_keys = 4 * _queries(81, 4)
     ds.extend(new_keys, np.arange(4))
     _, served_toks = ds.lookup(new_keys)
     assert (np.asarray(served_toks)[:, 0] == np.arange(4)).all()

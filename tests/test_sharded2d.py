@@ -30,6 +30,7 @@ _MESHES = {8: (2, 4), 16: (2, 8), 48: (6, 8)}
 
 _GRID_CHILD = r"""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.search import Index, backends
 
 A, B = @A@, @B@
@@ -40,8 +41,8 @@ RT = 0.999  # high-recall regime: bin layouts align -> exact parity
 rng = np.random.default_rng(7)
 db = jnp.asarray(rng.normal(size=(N, D)), jnp.float32)
 q = jnp.asarray(rng.normal(size=(M, D)), jnp.float32)
-mesh1 = jax.make_mesh((NDEV,), ("model",))
-mesh2 = jax.make_mesh((A, B), ("data", "model"))
+mesh1 = make_mesh((NDEV,), ("model",))
+mesh2 = make_mesh((A, B), ("data", "model"))
 
 CONFIGS = [("mips", "f32"), ("l2", "f32"), ("cosine", "f32"),
            ("l2", "bf16"), ("mips", "int8"), ("cosine", "int8")]
@@ -98,6 +99,7 @@ publish({"cases": report, "ndev": NDEV, "host_waves": waves})
 
 _CLUSTER_CHILD = r"""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.search import Index, exact_search
 
 A, B = @A@, @B@
@@ -110,8 +112,8 @@ db = jnp.asarray(centers[rng.integers(0, 64, N)]
                  + rng.normal(size=(N, D)), jnp.float32)
 q = jnp.asarray(centers[rng.integers(0, 64, M)]
                 + rng.normal(size=(M, D)), jnp.float32)
-mesh1 = jax.make_mesh((NDEV,), ("model",))
-mesh2 = jax.make_mesh((A, B), ("data", "model"))
+mesh1 = make_mesh((NDEV,), ("model",))
+mesh2 = make_mesh((A, B), ("data", "model"))
 
 oracle = Index.build(db, metric="l2", k=K, backend="xla",
                      recall_target=0.95, cluster="auto")

@@ -1,0 +1,120 @@
+"""Compile the Pallas scan kernels for a TPU v5e that is described, not
+attached.
+
+Every other test runs the kernels in interpret mode, which accepts block
+shapes and VMEM footprints that the TPU compiler (Mosaic) refuses.  Here
+each kernel is lowered and compiled by the real TPU compiler at the tiles
+the planner picks for the paper's Table 2 workloads on ``tpu_v5e``, and
+the compiled program must contain the kernel (``tpu_custom_call``).
+Nothing runs, so this says nothing about results or speed.
+
+The topology is described inside a module-scoped fixture (never at import
+time): only one process at a time may load the TPU compiler library, and
+pytest-xdist workers all import this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.knn_workloads import KNN_WORKLOADS
+from repro.core.binning import round_up
+from repro.kernels.partial_reduce import (
+    partial_reduce_fused,
+    partial_reduce_packed,
+)
+from repro.search.plan import plan_search
+
+_STORED = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8,
+           "int4": jnp.int8}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """A described-chip compile is written to the persistent cache but can
+    never be read back without the chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+
+
+def _kernel_args(workload: str, storage: str, sharding):
+    """Planner-chosen tiles and packed operand shapes, as ``Index.build``
+    lays them out (``repro.search.packed._layout``) for one query block."""
+    w = KNN_WORKLOADS[workload]
+    p = plan_search(n=w.n, d=w.d, k=w.k, metric=w.metric,
+                    recall_target=w.recall_target, backend="pallas",
+                    device="tpu_v5e", storage=storage)
+    block_n = p.bin_size * max(1, p.block_n // p.bin_size)
+    n_pad = round_up(max(w.n, block_n), block_n)
+    int4 = storage == "int4"
+    d_pad = round_up(w.d, 256 if int4 else 128)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    operands = [
+        shaped((p.query_block, w.d), jnp.float32),
+        shaped((n_pad, d_pad // 2 if int4 else d_pad), _STORED[storage]),
+        shaped((1, n_pad), jnp.float32),
+    ]
+    if storage in ("int8", "int4"):
+        operands.append(shaped((1, n_pad), jnp.float32))
+    tiles = dict(bin_size=p.bin_size, block_m=p.block_m, block_n=block_n,
+                 int4_packed=int4)
+    return p, operands, tiles
+
+
+def _assert_kernel_compiles(fn, operands):
+    compiled = jax.jit(fn).lower(*operands).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("workload,storage", [
+    ("sift1m", "f32"),
+    ("sift1m", "bf16"),
+    ("sift1m", "int8"),
+    ("sift1m", "int4"),
+    ("glove1.2m", "f32"),
+])
+def test_fused_kernel_compiles_at_planned_tiles(
+    one_chip, no_persistent_cache, workload, storage
+):
+    p, operands, tiles = _kernel_args(workload, storage, one_chip)
+    _assert_kernel_compiles(
+        lambda *ops: partial_reduce_fused(*ops, k_scan=p.k_scan, **tiles),
+        operands,
+    )
+
+
+def test_two_pass_kernel_compiles_at_planned_tiles(
+    one_chip, no_persistent_cache
+):
+    _, operands, tiles = _kernel_args("sift1m", "f32", one_chip)
+    _assert_kernel_compiles(
+        lambda *ops: partial_reduce_packed(*ops, **tiles), operands
+    )
