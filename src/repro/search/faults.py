@@ -19,7 +19,7 @@ Injection points (``INJECTION_POINTS``)
   ``serve.worker``     start of each ``SearchServer`` service cycle (the
                        worker-loop heartbeat; a ``WorkerDeath`` here kills
                        the worker *between* batches — queue intact)
-  ``serve.staging_alloc`` bucket selection + host staging-buffer gather
+  ``serve.staging_alloc`` host staging-buffer gather, once the bucket is chosen
   ``serve.transfer``   just before the host->device query copy
   ``serve.dispatch``   just before the coalesced ``index.search`` dispatch
                        (the retry loop's point: ``TransientFault`` here is

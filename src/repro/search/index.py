@@ -30,6 +30,7 @@ from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.binning import BinPlan, plan_bins, round_up
@@ -746,23 +747,28 @@ class Index:
         >>> int(indices[0, 0])  # e_0's best match is row 0
         0
         """
-        queries = jnp.asarray(queries)
-        if queries.ndim != 2:
-            raise ValueError(f"queries must be (M, D), got {queries.shape}")
-        if queries.shape[1] != self.dim:
-            raise ValueError(
-                f"query dim {queries.shape[1]} != index dim {self.dim}"
-            )
-        if self.spec.dtype is not None:
-            queries = queries.astype(jnp.dtype(self.spec.dtype))
-        if queries.shape[0] <= self.spec.query_block:
-            return SearchResult(*self._search_block(queries))
-        if self.spec.stream and self.spec.residency != "host":
-            # The host tier's wave driver stages segments from Python, so
-            # multi-block batches run the (bit-identical) per-block loop —
-            # each block still re-streams the database once.
-            return self._search_stream(queries)
-        return self._search_loop(queries)
+        # A profiler span (recorded only while a trace runs) over the host
+        # work of a search: validation, cast, pads, cache lookup, dispatch.
+        with TraceAnnotation("index.search"):
+            queries = jnp.asarray(queries)
+            if queries.ndim != 2:
+                raise ValueError(
+                    f"queries must be (M, D), got {queries.shape}"
+                )
+            if queries.shape[1] != self.dim:
+                raise ValueError(
+                    f"query dim {queries.shape[1]} != index dim {self.dim}"
+                )
+            if self.spec.dtype is not None:
+                queries = queries.astype(jnp.dtype(self.spec.dtype))
+            if queries.shape[0] <= self.spec.query_block:
+                return SearchResult(*self._search_block(queries))
+            if self.spec.stream and self.spec.residency != "host":
+                # The host tier stages its segment waves from Python, so
+                # multi-block batches run the (bit-identical) per-block
+                # loop — each block still re-streams the database once.
+                return self._search_stream(queries)
+            return self._search_loop(queries)
 
     def _batch_axis_for(self, rows: int) -> Optional[str]:
         """Query batch axis, dropped when it does not divide the block."""

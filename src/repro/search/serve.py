@@ -89,6 +89,23 @@ compares each dispatch's measured wall against the plan's Eq. 10/20
 prediction, degrading ``health()`` when the calibrated ratio leaves
 ``ServeConfig.drift_band``.  Span timings follow the server's clock, so
 virtual-clock servers produce deterministic traces.
+
+Profiler spans (``jax.profiler.TraceAnnotation``, on the device trace's
+clock, recorded only while a profiler trace runs): the wall-clock worker
+names every phase of its loop, so a device-idle gap reads as what the
+worker was doing.  Per loop pass, in order: ``serve.idle`` (waiting on an
+empty queue), ``serve.hold`` / ``serve.hold.inflight`` (holding the
+``max_delay_s`` window with nothing / a dispatched batch in flight),
+``serve.coalesce`` (popping the batch and choosing its bucket; after a
+retryable fault, the backoff and re-forming), ``serve.stage`` (host gather
+and put), ``serve.dispatch[<bucket>]`` (the enqueue), ``serve.account``
+(request traces, counters and registry of the dispatch), then for the
+previous batch ``serve.ready`` (waiting on the device), ``serve.fetch``
+(the device-to-host copies) and ``serve.scatter`` (ticket completion,
+registry, drift, and the release of the batch's device buffers), and last
+``serve.release`` (dropping the dispatched batch's staged query copy, whose
+device buffer can wait until the device has read it).
+``Index.search`` adds ``index.search``.
 """
 from __future__ import annotations
 
@@ -809,27 +826,30 @@ class SearchServer:
         self._fire("serve.worker")
         cfg = self.config
         t_start = time.perf_counter()
-        with self._lock:
-            batch = self._take_batch_locked(self._now())
+        with TraceAnnotation("serve.coalesce"):
+            with self._lock:
+                batch = self._take_batch_locked(self._now())
+                if batch is not None:
+                    self._not_full.notify_all()
             if batch is not None:
-                self._not_full.notify_all()
+                t_pop = self._now()
+                rows = sum(t.rows for t in batch)
+                bucket = self._bucket_for(rows)
         if batch is None:
             self._finalize(self._pop_inflight())
             return False
-        t_pop = self._now()
         attempt = 0
         while True:
             try:
-                # bucket/stage inside the guard too: an allocation failure
-                # on a huge oversize request must fail its tickets, not kill
-                # the worker thread with the popped batch stranded.
+                # stage inside the guard: an allocation failure on a huge
+                # oversize request must fail its tickets, not kill the
+                # worker thread with the popped batch stranded.
                 self._fire("serve.staging_alloc")
                 t_coalesced = self._now()
-                rows = sum(t.rows for t in batch)
-                bucket = self._bucket_for(rows)
-                buf = self._stage(bucket, batch)
-                self._fire("serve.transfer")
-                q = jnp.asarray(buf)
+                with TraceAnnotation("serve.stage"):
+                    buf = self._stage(bucket, batch)
+                    self._fire("serve.transfer")
+                    q = jnp.asarray(buf)
                 t_staged = self._now()
                 # perf_counter BEFORE the injection point: an injected
                 # delay lands inside the drift monitor's measured window.
@@ -863,46 +883,54 @@ class SearchServer:
                 attempt += 1
                 self._stats.inc("dispatch_retries")
                 SERVE_EVENTS.inc("dispatch_retries")
-                self._backoff(cfg.retry_backoff_s * (2 ** (attempt - 1)))
-                # Deadlines keep ticking through backoff: drop expired
-                # tickets rather than dispatch dead work on the retry.
-                batch = self._expire_batch(batch, self._now())
+                with TraceAnnotation("serve.coalesce"):
+                    self._backoff(cfg.retry_backoff_s * (2 ** (attempt - 1)))
+                    # Deadlines keep ticking through backoff: drop expired
+                    # tickets rather than dispatch dead work on the retry.
+                    batch = self._expire_batch(batch, self._now())
+                    if batch:
+                        rows = sum(t.rows for t in batch)
+                        bucket = self._bucket_for(rows)
                 if not batch:
                     return True
             except Exception as e:  # scatter the failure, keep serving
                 self._fail_batch(batch, e)
                 return True
-        t_dispatched = self._now()
-        for t in batch:
-            tr = t.trace
-            if tr is not None:
-                # Contiguous stage spans on the server clock: together
-                # with "scatter" (closed at completion) they tile the
-                # request's [submit, complete] window end to end.
-                tr.bucket = bucket
-                tr.retries = attempt
-                tr.span("queue", t.submitted_at, t_pop)
-                tr.span("coalesce", t_pop, t_coalesced)
-                tr.span("stage", t_coalesced, t_staged)
-                tr.span("dispatch", t_staged, t_dispatched)
-                tr.dispatched_at = t_dispatched
-        self._stats.inc("batches")
-        self._stats.inc("coalesced_requests", len(batch))
-        self._stats.inc("dispatched_rows", rows)
-        self._stats.inc("padded_rows", bucket - rows)
-        SERVE_EVENTS.inc("batches")
-        SERVE_EVENTS.inc("coalesced_requests", len(batch))
-        SERVE_EVENTS.inc("padded_rows", bucket - rows)
-        reg = telemetrylib.registry()
-        reg.observe("repro_serve_batch_rows", rows, bucket=bucket)
-        live = self._stats["dispatched_rows"] + self._stats["padded_rows"]
-        if live:
-            reg.set_gauge(
-                "repro_serve_occupancy", self._stats["dispatched_rows"] / live
-            )
-        prev = self._pop_inflight()
-        self._inflight = (result, batch, bucket, t_disp0)
-        self._finalize(prev)
+        with TraceAnnotation("serve.account"):
+            t_dispatched = self._now()
+            for t in batch:
+                tr = t.trace
+                if tr is not None:
+                    # Contiguous stage spans on the server clock: together
+                    # with "scatter" (closed at completion) they tile the
+                    # request's [submit, complete] window end to end.
+                    tr.bucket = bucket
+                    tr.retries = attempt
+                    tr.span("queue", t.submitted_at, t_pop)
+                    tr.span("coalesce", t_pop, t_coalesced)
+                    tr.span("stage", t_coalesced, t_staged)
+                    tr.span("dispatch", t_staged, t_dispatched)
+                    tr.dispatched_at = t_dispatched
+            self._stats.inc("batches")
+            self._stats.inc("coalesced_requests", len(batch))
+            self._stats.inc("dispatched_rows", rows)
+            self._stats.inc("padded_rows", bucket - rows)
+            SERVE_EVENTS.inc("batches")
+            SERVE_EVENTS.inc("coalesced_requests", len(batch))
+            SERVE_EVENTS.inc("padded_rows", bucket - rows)
+            reg = telemetrylib.registry()
+            reg.observe("repro_serve_batch_rows", rows, bucket=bucket)
+            live = self._stats["dispatched_rows"] + self._stats["padded_rows"]
+            if live:
+                reg.set_gauge(
+                    "repro_serve_occupancy",
+                    self._stats["dispatched_rows"] / live,
+                )
+        self._finalize(self._pop_inflight((result, batch, bucket, t_disp0)))
+        with TraceAnnotation("serve.release"):
+            # The staged query copy of the batch just dispatched: freeing
+            # its device buffer can wait until the device has read it.
+            del q
         self._maybe_sample_miss(buf, rows)
         # EWMA of service time feeds the Overloaded retry-after estimate.
         elapsed = time.perf_counter() - t_start
@@ -912,8 +940,13 @@ class SearchServer:
         )
         return True
 
-    def _pop_inflight(self) -> Optional[tuple]:
-        entry, self._inflight = self._inflight, None
+    def _pop_inflight(
+        self, replace: Optional[tuple] = None
+    ) -> Optional[tuple]:
+        """The in-flight entry, which ``replace`` takes the place of.  Hand
+        it straight to ``_finalize`` (no caller variable keeps it), so that
+        the batch's device buffers are released inside its spans."""
+        entry, self._inflight = self._inflight, replace
         return entry
 
     def _finalize(self, entry: Optional[tuple]) -> None:
@@ -927,14 +960,17 @@ class SearchServer:
         if entry is None:
             return
         result, batch, bucket, t_disp0 = entry
+        del entry  # ``result`` alone holds the device buffers now
         try:
-            self._fire("serve.scatter")
-            result.values.block_until_ready()
+            with TraceAnnotation("serve.ready"):
+                self._fire("serve.scatter")
+                result.values.block_until_ready()
             # Dispatch-to-ready wall: the measured side of the roofline
             # drift ratio for this bucket.
             measured_s = time.perf_counter() - t_disp0
-            values = np.asarray(result.values)
-            indices = np.asarray(result.indices)
+            with TraceAnnotation("serve.fetch"):
+                values = np.asarray(result.values)
+                indices = np.asarray(result.indices)
         except faultslib.WorkerDeath as e:
             # The dispatch already ran; its device-side work is lost with
             # the dying worker.  Fail the tickets with the typed error
@@ -948,32 +984,35 @@ class SearchServer:
             # waiter; fail the batch's tickets instead and keep serving.
             self._fail_batch(batch, e)
             return
-        now = self._now()
-        latencies = []
-        with self._lock:  # one acquisition per batch, not per ticket
-            for t in batch:
-                tr = t.trace
-                if tr is not None and tr.dispatched_at is not None:
-                    tr.span("scatter", tr.dispatched_at, now)
-                t._complete(
-                    SearchResult(
-                        values[t._offset : t._offset + t.rows, : t.k],
-                        indices[t._offset : t._offset + t.rows, : t.k],
-                    ),
-                    now,
-                )
-                if t.latency_s is not None:
-                    self._latency_sum += t.latency_s
-                    latencies.append(t.latency_s)
-            self._stats.inc("completed_requests", len(batch))
-        reg = telemetrylib.registry()
-        for lat in latencies:
-            reg.observe("repro_serve_request_latency_seconds", lat)
-        reg.observe(
-            "repro_serve_dispatch_wall_seconds", measured_s,
-            bucket=bucket,
-        )
-        self._record_drift(bucket, measured_s)
+        with TraceAnnotation("serve.scatter"):
+            now = self._now()
+            latencies = []
+            with self._lock:  # one acquisition per batch, not per ticket
+                for t in batch:
+                    tr = t.trace
+                    if tr is not None and tr.dispatched_at is not None:
+                        tr.span("scatter", tr.dispatched_at, now)
+                    t._complete(
+                        SearchResult(
+                            values[t._offset : t._offset + t.rows, : t.k],
+                            indices[t._offset : t._offset + t.rows, : t.k],
+                        ),
+                        now,
+                    )
+                    if t.latency_s is not None:
+                        self._latency_sum += t.latency_s
+                        latencies.append(t.latency_s)
+                self._stats.inc("completed_requests", len(batch))
+            reg = telemetrylib.registry()
+            reg.observe_many("repro_serve_request_latency_seconds", latencies)
+            reg.observe(
+                "repro_serve_dispatch_wall_seconds", measured_s,
+                bucket=bucket,
+            )
+            self._record_drift(bucket, measured_s)
+            # Release the batch's device buffers here, inside the span: on
+            # a TPU host that takes about as long as the scatter itself.
+            del result
 
     def _maybe_sample_miss(self, buf: np.ndarray, live_rows: int) -> None:
         """Served-query cluster-miss monitor: every Nth batch, score a few
@@ -1086,23 +1125,28 @@ class SearchServer:
                 if not self._queue:
                     # idle: scatter any in-flight batch, then sleep on work
                     if self._inflight is None:
-                        self._work.wait(0.05)
+                        with TraceAnnotation("serve.idle"):
+                            self._work.wait(0.05)
                 else:
                     # coalescing window: hold the batch open for late
                     # arrivals until it fills or the head request's window
-                    # expires
+                    # expires.  Only this thread sets ``_inflight``, so the
+                    # span's name holds for the whole wait.
                     deadline = (
                         self._queue[0].submitted_at + cfg.max_delay_s
                     )
-                    while (
-                        self._queue
-                        and self._pending_rows < self.max_batch
-                        and not self._closed
-                    ):
-                        remaining = deadline - self._now()
-                        if remaining <= 0:
-                            break
-                        self._work.wait(remaining)
+                    held = ("serve.hold" if self._inflight is None
+                            else "serve.hold.inflight")
+                    with TraceAnnotation(held):
+                        while (
+                            self._queue
+                            and self._pending_rows < self.max_batch
+                            and not self._closed
+                        ):
+                            remaining = deadline - self._now()
+                            if remaining <= 0:
+                                break
+                            self._work.wait(remaining)
             self._service_once()
         self._finalize(self._pop_inflight())
 
@@ -1199,7 +1243,7 @@ class SearchServer:
             return
         self._drift.record(str(bucket), measured_s, predicted)
         telemetrylib.registry().set_gauge(
-            "repro_serve_drift", self._drift.report()["value"]
+            "repro_serve_drift", self._drift.value
         )
 
     def precompile(self) -> int:

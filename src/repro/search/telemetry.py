@@ -65,6 +65,7 @@ True
 from __future__ import annotations
 
 import collections
+import math
 import re
 import threading
 from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
@@ -219,6 +220,19 @@ class MetricsRegistry:
             if hist is None:
                 hist = series[key] = _Histogram(self._window)
             hist.observe(value)
+
+    def observe_many(self, name: str, values: Iterable[float],
+                     **labels) -> None:
+        """Record several samples of one series in order, under one lock
+        acquisition (the same series :meth:`observe` would build)."""
+        key = _label_key(labels)
+        with self._lock:
+            series = self._histograms.setdefault(name, {})
+            hist = series.get(key)
+            if hist is None:
+                hist = series[key] = _Histogram(self._window)
+            for value in values:
+                hist.observe(value)
 
     def describe(self, name: str, help_text: str) -> None:
         """Attach a ``# HELP`` line to ``name`` in the Prometheus export."""
@@ -520,13 +534,18 @@ def chrome_trace(traces: Iterable[RequestTrace]) -> dict:
 
 
 class _BucketDrift:
-    __slots__ = ("samples", "warmup_ratios", "baseline", "ewma")
+    __slots__ = ("samples", "warmup_ratios", "baseline", "ewma", "drift",
+                 "distance")
 
     def __init__(self):
         self.samples = 0
         self.warmup_ratios: List[float] = []
         self.baseline: Optional[float] = None
         self.ewma: Optional[float] = None
+        # ewma / baseline once calibrated, and |log| of it: how far the
+        # bucket is from on-model, which ranks the buckets.
+        self.drift: Optional[float] = None
+        self.distance = 0.0
 
 
 class DriftMonitor:
@@ -570,6 +589,9 @@ class DriftMonitor:
         self.alpha = float(alpha)
         self._lock = threading.Lock()
         self._buckets: Dict[str, _BucketDrift] = {}
+        # The calibrated drift furthest from on-model (None until a bucket
+        # calibrates), kept current by record() so reading it costs no scan.
+        self._worst: Optional[float] = None
 
     def record(
         self, bucket, measured_s: float, predicted_s: float
@@ -593,6 +615,25 @@ class DriftMonitor:
                 if len(st.warmup_ratios) >= self.warmup:
                     st.baseline = float(np.median(st.warmup_ratios))
                     st.warmup_ratios = []
+            if st.baseline not in (None, 0.0):
+                st.drift = st.ewma / st.baseline
+                st.distance = abs(math.log(st.drift))
+                # Ties go to the first bucket in key order.
+                worst = None
+                for _, b in sorted(self._buckets.items()):
+                    if b.drift is not None and (
+                        worst is None or b.distance > worst.distance
+                    ):
+                        worst = b
+                self._worst = worst.drift
+
+    @property
+    def value(self) -> float:
+        """The headline drift: the worst calibrated bucket's normalized
+        ratio, 1.0 while every bucket is still warming up (the ``value`` of
+        :meth:`report`)."""
+        worst = self._worst
+        return 1.0 if worst is None else float(worst)
 
     def report(self) -> dict:
         """Drift report: headline ``value`` (worst calibrated bucket's
@@ -600,26 +641,17 @@ class DriftMonitor:
         ``in_band``, and the per-bucket evidence."""
         lo, hi = self.band
         with self._lock:
-            per_bucket = {}
-            worst: Optional[float] = None
-            for key, st in sorted(self._buckets.items()):
-                drift = (
-                    st.ewma / st.baseline
-                    if st.baseline not in (None, 0.0) and st.ewma is not None
-                    else None
-                )
-                per_bucket[key] = {
+            per_bucket = {
+                key: {
                     "samples": st.samples,
                     "ratio_ewma": st.ewma,
                     "baseline": st.baseline,
-                    "drift": drift,
-                    "in_band": drift is None or lo <= drift <= hi,
+                    "drift": st.drift,
+                    "in_band": st.drift is None or lo <= st.drift <= hi,
                 }
-                if drift is not None and (
-                    worst is None
-                    or abs(np.log(drift)) > abs(np.log(worst))
-                ):
-                    worst = drift
+                for key, st in sorted(self._buckets.items())
+            }
+            worst = self._worst
         value = 1.0 if worst is None else float(worst)
         return {
             "value": value,
@@ -632,6 +664,7 @@ class DriftMonitor:
     def reset(self) -> None:
         with self._lock:
             self._buckets.clear()
+            self._worst = None
 
 
 # -- process-global registry --------------------------------------------------
