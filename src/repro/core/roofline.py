@@ -140,18 +140,23 @@ def partial_reduce_fused_cost(
 
     FLOPs  = 2MND (the einsum, unchanged)
     bytes  = dtype(MD) + db_bytes * ceil(M/ib) * ND + 8 M k_scan
-    COPs   = C*M*N + M * (N/block_n) * k_scan * (k_scan + bins_per_block)
+    COPs   = C*M*N + M * (T * ceil128(bins_per_block) + k_scan * ceil128(L))
+
+    with T = ceil(N/block_n) database tiles, L = T * bins_per_block bins
+    and ceil128 rounding up to whole 128-lane rows.
 
     Versus :func:`partial_reduce_cost`, the ``2ML`` bin-winner HBM term
     (the (M, N/bin_size) score-tile round trip the two-pass select pays)
-    collapses to the O(M·k_scan) final result — the carry buffer lives in
-    VMEM across the database stream.  The database term uses the *integer*
+    collapses to the O(M·k_scan) final result — the winner buffer lives
+    in VMEM across the database stream.  The database term uses the *integer*
     pass count ``ceil(M/ib)``: each query-block grid row streams the whole
     database once, so a fractional M/ib would under-price small batches.
-    The extra COP term prices the in-VMEM merge (k_scan first-lane max
-    extractions over k_scan + bins_per_block lanes, once per database
-    tile); amortized over the tile's block_n rows it is a lower-order
-    term, priced so tile escalation cannot pretend the merge is free.
+    The extra COP term prices the in-VMEM select: each tile stores its
+    bin winners into a winner buffer (one select per 128-lane row it
+    touches), and once per query block k_scan max extractions run over
+    the buffer's ceil128(L) lanes.  Amortized over the database rows it
+    is a lower-order term, priced so tile escalation cannot pretend the
+    select is free.
     """
     if db_bytes is None:
         db_bytes = dtype_bytes
@@ -162,10 +167,13 @@ def partial_reduce_fused_cost(
         + db_bytes * passes * n * d
         + 8.0 * m * k_scan
     )
-    tiles = max(1.0, n / max(1, block_n))
+    tiles = max(1, -(-n // max(1, block_n)))
+    lanes = 128
+    placed = -(-bins_per_block // lanes) * lanes
+    bins = -(-tiles * bins_per_block // lanes) * lanes
     cops = (
         cops_per_dot * m * n
-        + m * tiles * k_scan * (k_scan + bins_per_block)
+        + m * (tiles * placed + k_scan * bins)
     )
     return KernelCost(flops=flops, hbm_bytes=hbm, cops=cops)
 

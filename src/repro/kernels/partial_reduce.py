@@ -11,21 +11,26 @@ Two selection back-ends share that scan:
     (block_m, bins_per_block) bin-winner tile to HBM and the caller merges
     the (M, N/bin_size) winners with ``lax.top_k``.  Simple, and the
     parity oracle for the fused path.
-  * **Fused** (``partial_reduce_fused``): a (block_m, k_scan) candidate
-    buffer (values + global indices) lives in VMEM scratch and is carried
-    across the sequential j-loop; each grid step merges its tile's bin
-    winners into the carry, and only the final (M, k_scan) result is ever
-    written to HBM (Eq. 20: database bytes + O(k), no score-tile term).
+  * **Fused** (``partial_reduce_fused``): a winner buffer in VMEM
+    scratch holds every bin winner (value + global index) of the query
+    block, laid out as (ceil(L/128), block_m, 128) for L bins.  Each grid
+    step of the sequential j-loop stores its tile's winners at their
+    global bin positions (a load, select and store of one 128-lane row
+    per 128 bins); the last step selects the top-k_scan of the buffer
+    once, and only that (M, k_scan) result is ever written to HBM (Eq. 20:
+    database bytes + O(k), no score-tile term).  The result is the one
+    ``lax.top_k`` gives over the two-pass winner row, bit for bit.
     Masked winners (tombstones, padded tail) carry the sentinel index -1
     alongside their -inf value, so they can never collide with a live row
-    after the merge.
+    after the select.
 
 COP accounting (Appendix A.5): the in-tile reduction uses exactly 3
 coefficient-wise ops per score (compare/select for the running max, the
 iota compare, and the index min) = the paper's C=3.  The bias row fuses both
 the non-power-of-2 masking COP and the L2 halved-norm COP into one add.
-The fused merge adds O((k_scan + bins_per_block) * k_scan) vector ops per
-tile — amortized over block_n database rows, a lower-order COP term.
+The fused select adds one 128-lane select per tile and k_scan extractions
+over the ceil(L/128)·128-lane buffer per query block — amortized over the
+database rows, a lower-order COP term.
 
 Tiling contract (enforced by ops.py / repro.search.packed):
   * D is padded to a multiple of 128 (MXU lane width; 256 for the packed
@@ -40,6 +45,7 @@ Tiling contract (enforced by ops.py / repro.search.packed):
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -54,11 +60,36 @@ __all__ = [
     "partial_reduce_fused_pallas",
     "partial_reduce_packed",
     "partial_reduce_pallas",
+    "SELECT_MODE",
+    "winner_buffer_bytes",
 ]
 
 # Same sentinel the search stages use (stages.MASK_VALUE); redeclared here
 # so the kernel layer stays import-free of repro.search.
 _MASK = float(jnp.finfo(jnp.float32).min)
+_LANES = 128
+
+# How the fused kernel selects: every bin winner of a query block is kept
+# in VMEM and the top-k_scan is taken once, at the block's last tile.  The
+# label its dispatches are counted under (repro.search.backends).
+SELECT_MODE = "deferred"
+# Query rows the final select works on at once: four f32 sublane tiles,
+# whose independent extraction chains overlap (on a v5e, 1.2-2.0 ms less
+# kernel time a 4,096-query block than one tile at a time, at k_scan 10
+# and 20).
+_SELECT_ROWS = 32
+
+
+def _winner_chunks(n: int, bin_size: int) -> int:
+    """128-lane chunks that hold the bin winners of an n-row database."""
+    return -(-(n // bin_size) // _LANES)
+
+
+def winner_buffer_bytes(block_m: int, n: int, bin_size: int) -> int:
+    """VMEM bytes of the fused kernel's winner buffer: an f32 value and an
+    int32 global id for every bin of an n-row (block-padded) database, for
+    each of the block_m query rows, in whole 128-lane chunks."""
+    return 2 * 4 * block_m * _winner_chunks(n, bin_size) * _LANES
 
 
 def _effective_block_m(m: int, block_m: int, dtype) -> int:
@@ -130,11 +161,12 @@ def partial_reduce_fused(
     """Single-pass scan→select over packed operands (Eq. 20 fused path).
 
     Same operand contract as :func:`partial_reduce_packed`, but selection
-    is fused into the scan: the per-query top-``k_scan`` candidate buffer
-    is carried in VMEM across the database stream and only the final
-    (m, k_scan) result touches HBM.  Returns (values, indices), values
-    sorted descending per row; masked entries (fewer than k_scan live
-    candidates) hold ``-inf`` values and the sentinel index ``-1``.
+    is fused into the scan: every bin winner is kept in a VMEM buffer
+    across the database stream, the top-``k_scan`` is selected from it
+    once, and only that (m, k_scan) result touches HBM.  Returns (values,
+    indices), values sorted descending per row; masked entries (fewer
+    than k_scan live candidates) hold ``-inf`` values and the sentinel
+    index ``-1``.
     """
     m, d = queries.shape
     d_pad = database.shape[1] * (2 if int4_packed else 1)
@@ -230,54 +262,111 @@ def _reduce_tile(q_ref, x_ref, scale_ref, bias_ref, v_ref, a_ref,
     a_ref[...] = idx
 
 
-def _merge_topk_carry(cv, ci, tv, ti, k_scan: int):
-    """Merge a tile's bin winners into the running top-k_scan carry.
+def _spread(x, offset):
+    """A (rows, w <= 128) slice of bin winners as a full 128-lane row.
 
-    Iterative first-lane max extraction over the concatenated
-    (k_scan + bins_per_block) lanes: ties resolve to the lowest lane, and
-    because the carry (earlier database tiles, itself extraction-ordered)
-    precedes the tile winners (bin-ordered), tie order matches what
-    ``lax.top_k`` over the full two-pass winner row would produce.  No
-    ``lax.top_k``/gather inside the kernel — Mosaic only needs max, iota
-    compares and masked sums.
+    Column b lands on lane ``(offset + b) % 128``; the lanes no column
+    lands on hold junk that the caller's lane mask never selects.  One
+    column is a lane broadcast; wider slices are zero-filled to 128 lanes
+    and rotated by the (traced) offset.
     """
-    v = jnp.concatenate([cv, tv], axis=1)
-    i = jnp.concatenate([ci, ti], axis=1)
-    lanes = v.shape[1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
-    out_v, out_i = [], []
-    for _ in range(k_scan):
-        best = jnp.max(v, axis=1, keepdims=True)
-        hit = jnp.where(v == best, lane, lanes)
-        pos = jnp.min(hit, axis=1, keepdims=True)
-        sel = lane == pos
-        out_v.append(best)
-        out_i.append(jnp.sum(jnp.where(sel, i, 0), axis=1, keepdims=True))
-        # Retire BOTH halves of the extracted lane.  Masking only the value
-        # would let the lane win a later -inf tie with its stale index — on
-        # an all-tombstoned tile the first winner's index would then leak
-        # into every masked output slot (the phantom-duplicate bug this
-        # kernel exists to fix, resurfacing in VMEM).
-        v = jnp.where(sel, _MASK, v)
-        i = jnp.where(sel, -1, i)
-    return (
-        jnp.concatenate(out_v, axis=1),
-        jnp.concatenate(out_i, axis=1),
-    )
+    rows, w = x.shape
+    if w == 1:
+        return jnp.broadcast_to(x, (rows, _LANES))
+    if w < _LANES:
+        x = jnp.concatenate([x, jnp.zeros((rows, _LANES - w), x.dtype)], 1)
+    return pltpu.roll(x, offset, 1)
+
+
+def _record_winners(wv_ref, wi_ref, vmax, idx, first_bin):
+    """Store one tile's bin winners at their global bin positions.
+
+    The winner buffer is (chunks, block_m, 128): global bin p lives in
+    chunk ``p // 128``, lane ``p % 128``.  Each 128-lane piece of the
+    tile's winners costs one load, select and store of a chunk row —
+    no cross-lane reduction, no serial chain.  A piece whose lanes run
+    past lane 127 (bins_per_block neither divides nor is a multiple of
+    128) wraps its tail onto the head of the next chunk.
+    """
+    bpb = vmax.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    # A piece can straddle two chunks unless every piece starts at a
+    # multiple of its width.
+    wraps = _LANES % bpb != 0 and bpb % _LANES != 0
+    for t in range(0, bpb, _LANES):
+        w = min(_LANES, bpb - t)
+        pos = first_bin + t
+        chunk, off = pos // _LANES, pos % _LANES
+        v = _spread(vmax[:, t:t + w], off)
+        i = _spread(idx[:, t:t + w], off)
+        here = (lane >= off) & (lane < off + w)
+        wv_ref[chunk] = jnp.where(here, v, wv_ref[chunk])
+        wi_ref[chunk] = jnp.where(here, i, wi_ref[chunk])
+        if wraps:
+            @pl.when(off + w > _LANES)
+            def _wrap(chunk=chunk, off=off, v=v, i=i, w=w):
+                tail = lane < off + w - _LANES
+                wv_ref[chunk + 1] = jnp.where(tail, v, wv_ref[chunk + 1])
+                wi_ref[chunk + 1] = jnp.where(tail, i, wi_ref[chunk + 1])
+
+
+def _select_topk(wv_ref, wi_ref, v_ref, a_ref, k_scan: int):
+    """Write the top-``k_scan`` of every bin winner in the buffer, best
+    first, into the (block_m, k_scan) outputs.
+
+    Iterative max extraction, once per query block, ``_SELECT_ROWS`` query
+    rows at a time, so a group's (chunks, rows, 128) slice of the buffer
+    stays in registers.  Ties resolve to the lowest global id,
+    which is the lowest bin position (bin p's winner is a row of
+    [p·bin_size, (p+1)·bin_size)): the order ``lax.top_k`` over the
+    two-pass winner row gives.  Masked winners and the buffer's unused
+    lanes all hold (_MASK, -1), so their order cannot show.  An extracted
+    lane retires its value AND its id — retiring only the value would let
+    it win a later _MASK tie with a stale live id (the phantom-duplicate
+    bug, in VMEM).  No ``lax.top_k``/gather inside the kernel: Mosaic only
+    needs max/min reductions, compares and selects.
+    """
+    chunks, block_m, _ = wv_ref.shape
+    rows = math.gcd(block_m, _SELECT_ROWS)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, k_scan), 1)
+    no_id = jnp.iinfo(jnp.int32).max
+
+    def extract(t, carry):
+        v, i, out_v, out_i = carry
+        best = jnp.max(jnp.max(v, axis=0), axis=1, keepdims=True)
+        hit = v == best[None]
+        first = jnp.min(jnp.min(jnp.where(hit, i, no_id), axis=0),
+                        axis=1, keepdims=True)
+        sel = hit & (i == first[None])
+        return (jnp.where(sel, _MASK, v), jnp.where(sel, -1, i),
+                jnp.where(col == t, best, out_v),
+                jnp.where(col == t, first, out_i))
+
+    def group(g, state):
+        r = pl.multiple_of(g * rows, rows)
+        carry = (wv_ref[:, pl.ds(r, rows), :], wi_ref[:, pl.ds(r, rows), :],
+                 jnp.full((rows, k_scan), _MASK, jnp.float32),
+                 jnp.full((rows, k_scan), -1, jnp.int32))
+        _, _, out_v, out_i = jax.lax.fori_loop(0, k_scan, extract, carry)
+        v_ref[pl.ds(r, rows), :] = out_v
+        a_ref[pl.ds(r, rows), :] = out_i
+        return state
+
+    jax.lax.fori_loop(0, block_m // rows, group, 0)
 
 
 def _fused_tile(q_ref, x_ref, scale_ref, bias_ref, v_ref, a_ref,
-                cv_ref, ci_ref,
+                wv_ref, wi_ref,
                 *, block_n: int, bin_size: int, k_scan: int,
                 int4_packed: bool):
-    block_m = q_ref.shape[0]
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        # Fresh carry per query block: -inf values, sentinel indices.
-        cv_ref[...] = jnp.full((block_m, k_scan), _MASK, jnp.float32)
-        ci_ref[...] = jnp.full((block_m, k_scan), -1, jnp.int32)
+        # Fresh buffer per query block: lanes past the last bin stay
+        # (-inf, sentinel) for the whole stream.
+        wv_ref[...] = jnp.full(wv_ref.shape, _MASK, jnp.float32)
+        wi_ref[...] = jnp.full(wi_ref.shape, -1, jnp.int32)
 
     vmax, idx = _tile_winners(
         q_ref, x_ref, scale_ref, bias_ref,
@@ -286,14 +375,11 @@ def _fused_tile(q_ref, x_ref, scale_ref, bias_ref, v_ref, a_ref,
     # A fully-masked bin's winner is meaningless — pair its -inf value
     # with the sentinel index in-kernel so it can never alias a live row.
     idx = jnp.where(vmax > _MASK * 0.5, idx, -1)
-    cv, ci = _merge_topk_carry(cv_ref[...], ci_ref[...], vmax, idx, k_scan)
-    cv_ref[...] = cv
-    ci_ref[...] = ci
+    _record_winners(wv_ref, wi_ref, vmax, idx, j * vmax.shape[1])
 
     @pl.when(j == pl.num_programs(1) - 1)
-    def _flush():
-        v_ref[...] = cv_ref[...]
-        a_ref[...] = ci_ref[...]
+    def _select():
+        _select_topk(wv_ref, wi_ref, v_ref, a_ref, k_scan)
 
 
 def _partial_reduce_kernel(
@@ -327,18 +413,18 @@ def _partial_reduce_kernel_scaled(
                  block_n=block_n, bin_size=bin_size, int4_packed=int4_packed)
 
 
-def _fused_kernel(q_ref, x_ref, bias_ref, v_ref, a_ref, cv_ref, ci_ref,
+def _fused_kernel(q_ref, x_ref, bias_ref, v_ref, a_ref, wv_ref, wi_ref,
                   *, block_n, bin_size, k_scan, int4_packed):
-    _fused_tile(q_ref, x_ref, None, bias_ref, v_ref, a_ref, cv_ref, ci_ref,
+    _fused_tile(q_ref, x_ref, None, bias_ref, v_ref, a_ref, wv_ref, wi_ref,
                 block_n=block_n, bin_size=bin_size, k_scan=k_scan,
                 int4_packed=int4_packed)
 
 
 def _fused_kernel_scaled(q_ref, x_ref, scale_ref, bias_ref, v_ref, a_ref,
-                         cv_ref, ci_ref,
+                         wv_ref, wi_ref,
                          *, block_n, bin_size, k_scan, int4_packed):
     _fused_tile(q_ref, x_ref, scale_ref, bias_ref, v_ref, a_ref,
-                cv_ref, ci_ref,
+                wv_ref, wi_ref,
                 block_n=block_n, bin_size=bin_size, k_scan=k_scan,
                 int4_packed=int4_packed)
 
@@ -456,11 +542,12 @@ def partial_reduce_fused_pallas(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Single-pass scan→select: (values, indices), both (m, k_scan).
 
-    The top-k_scan carry lives in VMEM scratch across the sequential
-    j-loop (TPU grids iterate the last axis innermost), so per search the
-    only HBM traffic is the query block, the database stream and the
-    final (m, k_scan) result — the paper's Eq. 20 contract.  Values come
-    out sorted descending; masked entries hold (-inf, -1).
+    The winner buffer lives in VMEM scratch across the sequential j-loop
+    (TPU grids iterate the last axis innermost) and is reduced to the
+    top-k_scan once, at the query block's last database tile, so per
+    search the only HBM traffic is the query block, the database stream
+    and the final (m, k_scan) result — the paper's Eq. 20 contract.
+    Values come out sorted descending; masked entries hold (-inf, -1).
     """
     _validate_tiling(queries, database, block_m=block_m, block_n=block_n,
                      bin_size=bin_size, int4_packed=int4_packed)
@@ -471,6 +558,7 @@ def partial_reduce_fused_pallas(
     m, d = queries.shape
     n, w = database.shape
     grid = (m // block_m, n // block_n)
+    buffer = (_winner_chunks(n, bin_size), block_m, _LANES)
 
     in_specs = [
         pl.BlockSpec((block_m, d), lambda i, j: (i, 0)),
@@ -499,8 +587,8 @@ def partial_reduce_fused_pallas(
             jax.ShapeDtypeStruct((m, k_scan), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_m, k_scan), jnp.float32),
-            pltpu.VMEM((block_m, k_scan), jnp.int32),
+            pltpu.VMEM(buffer, jnp.float32),
+            pltpu.VMEM(buffer, jnp.int32),
         ],
         interpret=interpret,
     )(*operands)

@@ -37,6 +37,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.binning import plan_bins, round_up
 from repro.core.partial_reduce import partial_reduce_with_plan
 from repro.kernels.partial_reduce import (
+    SELECT_MODE,
     partial_reduce_fused,
     partial_reduce_packed,
     partial_reduce_pallas,
@@ -62,7 +63,9 @@ __all__ = [
     "MASK_VALUE",
     "TRACE_COUNTS",
     "DISPATCH_COUNTS",
+    "FUSED_SELECT_COUNTS",
     "CompileCache",
+    "count_dispatch",
     "cluster_search",
     "cluster_search_quant",
     "dense_search",
@@ -92,6 +95,10 @@ TRACE_COUNTS = telemetry.AtomicCounter()
 # an arbitrarily large query batch).
 DISPATCH_COUNTS = telemetry.AtomicCounter()
 
+# select mode -> dispatches whose program runs the fused scan→select
+# kernel (``count_dispatch``).
+FUSED_SELECT_COUNTS = telemetry.AtomicCounter()
+
 telemetry.registry().register_counter_dict(
     "repro_traces_total", TRACE_COUNTS, "backend",
     "jit traces per backend (steady state: zero growth)",
@@ -100,6 +107,21 @@ telemetry.registry().register_counter_dict(
     "repro_dispatches_total", DISPATCH_COUNTS, "backend",
     "device dispatches per backend (one per coalesced batch)",
 )
+telemetry.registry().register_counter_dict(
+    "repro_fused_select_dispatches_total", FUSED_SELECT_COUNTS, "mode",
+    "dispatches that run the fused scan→select kernel, by select mode",
+)
+
+
+def count_dispatch(backend: str, spec, clustered: bool) -> None:
+    """Count one device dispatch of ``spec`` on ``backend``, and the fused
+    kernel's select mode when the dispatched program runs it: the Pallas
+    dense path with ``fused_select`` on (a rescore implies
+    ``aggregate_to_topk``)."""
+    DISPATCH_COUNTS.inc(backend)
+    if (backend == "pallas" and not clustered
+            and spec.fused_select_enabled and spec.aggregate_to_topk):
+        FUSED_SELECT_COUNTS.inc(SELECT_MODE)
 
 
 def reset_trace_counts() -> None:
@@ -598,7 +620,7 @@ def pallas_search_packed_quant(
     second-pass work, inside Eq. 10's O(min(M, N)) budget.
 
     ``fused_select=True`` replaces the dispatch-level scan→cut with the
-    single-pass kernel: the top-``k_scan`` carry is selected in VMEM, so
+    single-pass kernel: the top-``k_scan`` is selected in VMEM, so
     the rescore consumes the kernel output directly and the (M, L)
     bin-winner tile never exists in HBM.
     """

@@ -59,6 +59,7 @@ from repro.core.roofline import (
     partial_reduce_cost,
     partial_reduce_fused_cost,
 )
+from repro.kernels.partial_reduce import winner_buffer_bytes
 from repro.search import cluster as clusterlib
 from repro.search import quant
 from repro.search import telemetry
@@ -357,15 +358,18 @@ def _vmem_budget(hw: Hardware) -> float:
 
 def _vmem_need(block_m: int, block_n: int, d_pad: int, dtype_bytes: int,
                bin_size: int, db_bytes: Optional[float] = None,
-               k_scan: int = 0) -> float:
+               fused_n: int = 0) -> float:
     """On-chip bytes one (block_m, block_n) grid step holds.
 
     ``db_bytes`` is the stored database tile's bytes/element (quantized
     tiers stream and hold narrower rows; int4 holds 0.5 — two nibbles per
-    stored byte); default: ``dtype_bytes``.  ``k_scan`` charges the fused
-    kernel's top-k carry — a persistent (block_m, k_scan) f32-value +
-    int32-index scratch pair that lives in VMEM across the whole database
-    stream, so it is budgeted alongside the per-step tiles.
+    stored byte); default: ``dtype_bytes``.  ``fused_n`` (the database
+    rows, for the fused kernel; 0 for the two-pass kernel) charges the
+    fused kernel's winner buffer — an f32 value and an int32 id for every
+    bin winner of the block-padded database, per query row
+    (``kernels.partial_reduce.winner_buffer_bytes``) — which lives in
+    VMEM across the whole database stream, so it is budgeted alongside
+    the per-step tiles.
 
     A stored tier narrower than the compute dtype is dequantized in VMEM
     (``kernels.partial_reduce._load_db_tile``): that costs a compute-dtype
@@ -390,7 +394,8 @@ def _vmem_need(block_m: int, block_n: int, d_pad: int, dtype_bytes: int,
         + unpack
         + block_m * block_n * 4                     # score tile (f32)
         + 2 * block_m * max(1, block_n // bin_size) * 4  # winners (val+idx)
-        + 2 * block_m * k_scan * 4                  # fused top-k carry
+        + (winner_buffer_bytes(block_m, round_up(fused_n, block_n), bin_size)
+           if fused_n else 0)                       # fused winner buffer
     )
 
 
@@ -405,21 +410,24 @@ def _plan_tiles(
     block_m: Optional[int] = None,
     max_block_n: Optional[int] = None,
     db_bytes: Optional[float] = None,
-    k_scan: int = 0,
+    fused_n: int = 0,
 ) -> Tuple[int, int]:
     """Initial kernel tile sizes from the on-chip memory model.
 
     VMEM per grid step holds the query tile (block_m, d_pad), the database
     tile (block_n, d_pad), the score tile (block_m, block_n) and the bin
-    winners.  Tiles honour the TPU tiling contract (sublane-multiple rows,
-    128-lane columns) and never exceed the data: ``block_n`` stops at the
-    bin-aligned database size, so a small database is not padded up to a
-    full default tile.  ``block_m`` may subsequently be *escalated* by
-    :func:`plan_search` to push the kernel off the memory wall (Eq. 10).
+    winners; the fused kernel (``fused_n`` rows) also holds its winner
+    buffer, which only a smaller ``block_m`` shrinks.  Tiles honour the
+    TPU tiling contract (sublane-multiple rows, 128-lane columns) and
+    never exceed the data: ``block_n`` stops at the bin-aligned database
+    size, so a small database is not padded up to a full default tile.
+    ``block_m`` may subsequently be *escalated* by :func:`plan_search` to
+    push the kernel off the memory wall (Eq. 10).
     """
     if db_bytes is None:
         db_bytes = dtype_bytes
     sublane = _SUBLANE.get(dtype_bytes, 8)
+    pinned_m = block_m is not None
     if block_m is None:
         block_m = DEFAULT_BLOCK_M if m is None else min(
             DEFAULT_BLOCK_M, max(sublane, round_up(m, sublane))
@@ -443,9 +451,13 @@ def _plan_tiles(
     g_anchor = max(1, DEFAULT_BLOCK_N // unit)
     g = min(g_data, g_anchor)
     while g > 1 and _vmem_need(
-        block_m, g * unit, d_pad, dtype_bytes, bin_size, db_bytes, k_scan
+        block_m, g * unit, d_pad, dtype_bytes, bin_size, db_bytes, fused_n
     ) > budget:
         g -= 1
+    while not pinned_m and block_m > sublane and _vmem_need(
+        block_m, g * unit, d_pad, dtype_bytes, bin_size, db_bytes, fused_n
+    ) > budget:
+        block_m = max(sublane, round_up(block_m // 2, sublane))
     return block_m, g * unit
 
 
@@ -468,10 +480,11 @@ def _escalate_block_m(
     The kernel grid streams the full database once per ``block_m`` query
     rows (Eq. 20's ``ib``), so a too-small query tile makes the kernel
     memory-bound regardless of N.  The model doubles ``block_m`` — within
-    the VMEM budget (which charges the fused carry at each candidate
-    size), the query batch, and a 1024-row cap — until the attainable
-    FLOP/s stop being memory-limited.  This is the planner reproducing
-    the paper's Fig. 2 reasoning as a *decision* instead of a figure.
+    the VMEM budget (which charges the fused winner buffer at each
+    candidate size), the query batch, and a 1024-row cap — until the
+    attainable FLOP/s stop being memory-limited.  This is the planner
+    reproducing the paper's Fig. 2 reasoning as a *decision* instead of a
+    figure.
     Costs come from the fused single-pass model (the kernel this tile
     actually feeds); ``num_bins`` stays in the signature for the legacy
     two-pass callers in older tests.
@@ -491,7 +504,7 @@ def _escalate_block_m(
             break
         bigger = min(cap, block_m * 2)
         if _vmem_need(bigger, block_n, d_pad, dtype_bytes, bin_size,
-                      db_bytes, ks) > _vmem_budget(hw):
+                      db_bytes, padded_n) > _vmem_budget(hw):
             break
         block_m = bigger
     return block_m
@@ -827,7 +840,7 @@ def plan_search(
     bm, bn = _plan_tiles(
         n, d_pad, bins.bin_size, m, dbytes, hw,
         block_m=block_m, max_block_n=max_block_n, db_bytes=sbytes,
-        k_scan=ks,
+        fused_n=n if backend == "pallas" else 0,
     )
     # Host residency materializes a (qb, segment_rows) score tile per
     # wave, not (qb, N) — size the query block against the wave shape.

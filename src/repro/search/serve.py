@@ -121,6 +121,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.search import backends
 from repro.search import cluster as clusterlib
 from repro.search import faults as faultslib
 from repro.search import telemetry as telemetrylib
@@ -1285,6 +1286,9 @@ class SearchServer:
             "uptime_s": self._now() - self._started_at,
             "traced_requests": len(self._traces) if self._traces else 0,
             "cache": self.index.cache_info(),
+            # Process-wide, like DISPATCH_COUNTS: dispatches that ran the
+            # fused scan→select kernel, by its select mode.
+            "fused_select": dict(backends.FUSED_SELECT_COUNTS),
         }
         live = out["dispatched_rows"] + out["padded_rows"]
         out["occupancy"] = out["dispatched_rows"] / live if live else 0.0

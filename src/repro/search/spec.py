@@ -75,7 +75,7 @@ class SearchSpec:
         inside jit is pathologically slow on CPU XLA (minutes at L=256),
         and ``lax.top_k`` over the L candidates is exact either way.
       fused_select: run the Pallas backend's single-pass scan→select
-        kernel (the top-k carry merges in VMEM during the scan — Eq. 20
+        kernel (the top-k is selected in VMEM during the scan — Eq. 20
         traffic: database bytes + O(k), no (M, N/bin_size) score-tile
         round trip).  ``None`` (default) resolves to True on the pallas
         backend whenever selection happens (``aggregate_to_topk`` or an
@@ -182,7 +182,7 @@ class SearchSpec:
         if self.fused_select and not self.aggregate_to_topk:
             raise ValueError(
                 "fused_select=True needs aggregate_to_topk=True: the "
-                "fused kernel's VMEM carry *is* the top-k selection, so "
+                "fused kernel's VMEM select *is* the top-k selection, so "
                 "there are no raw bin winners to return.  Use "
                 "fused_select=False (or None) for the two-pass scan."
             )

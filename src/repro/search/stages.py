@@ -26,7 +26,7 @@ replaces the streamed scan's candidate set with gathered slots, and
 
 On the Pallas backend the fused kernel
 (``repro.kernels.partial_reduce.partial_reduce_fused``) subsumes the
-scan → ``merge_topk`` pair: the top-``k_scan`` carry is merged in VMEM
+scan → ``merge_topk`` pair: the top-``k_scan`` is selected in VMEM
 during the scan, so the composed pipeline degenerates to
 score+scan+select (one dispatch, Eq. 20 traffic) followed by the same
 rescore/finalize stages.  The two-pass composition remains the parity

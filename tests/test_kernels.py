@@ -7,7 +7,10 @@ import pytest
 
 from repro.core.knn import exact_mips
 from repro.kernels.ops import l2_topk, mips_topk
-from repro.kernels.partial_reduce import partial_reduce_pallas
+from repro.kernels.partial_reduce import (
+    partial_reduce_fused,
+    partial_reduce_pallas,
+)
 from repro.kernels.ref import partial_reduce_ref
 
 
@@ -37,6 +40,82 @@ def test_kernel_matches_ref_shapes(m, n, d, bin_size, block_m, block_n):
     rv, ri = partial_reduce_ref(q, db, bias, bin_size=bin_size)
     np.testing.assert_allclose(np.asarray(kv), np.asarray(rv), atol=1e-4)
     np.testing.assert_array_equal(np.asarray(ki), np.asarray(ri))
+
+
+_MASK = float(jnp.finfo(jnp.float32).min)
+
+
+def _two_pass_top_k(q, db, bias, k, **tiles):
+    """The oracle: every bin winner from the two-pass kernel, masked ones
+    paired with the sentinel id, then ``lax.top_k`` over the winner row
+    (padded with sentinels when k exceeds the bins)."""
+    v, i = partial_reduce_pallas(q, db, bias, interpret=True, **tiles)
+    i = jnp.where(v > _MASK * 0.5, i, -1)
+    short = max(0, k - v.shape[1])
+    v = jnp.pad(v, ((0, 0), (0, short)), constant_values=_MASK)
+    i = jnp.pad(i, ((0, 0), (0, short)), constant_values=-1)
+    top_v, pos = jax.lax.top_k(v, k)
+    return top_v, jnp.take_along_axis(i, pos, axis=1)
+
+
+# (m, n, bin_size, block_m, block_n, k): n // bin_size bins, block_n //
+# bin_size of them per tile.
+_FUSED_CASES = {
+    # one bin a tile, 300 bins: three 128-lane chunks, the last partial
+    "one_bin_per_tile": (16, 2400, 8, 8, 8, 7),
+    # 3 bins a tile: pieces straddle chunk boundaries (the roll + wrap)
+    "three_bins_per_tile": (16, 2400, 16, 8, 48, 6),
+    # 64 bins a tile, 320 bins; two 32-row select groups
+    "sixty_four_bins_per_tile": (64, 5120, 4, 64, 256, 9),
+    # 192 bins a tile: a full 128-lane piece and a 64-lane piece
+    "one_hundred_ninety_two_bins_per_tile": (8, 1152, 2, 8, 384, 10),
+    # 256 bins a tile: two aligned 128-lane pieces, no roll
+    "two_hundred_fifty_six_bins_per_tile": (8, 768, 1, 8, 256, 4),
+    # a single query row: the kernel clamps block_m to 8 (the served case)
+    "one_row": (1, 2400, 8, 256, 8, 10),
+    # 128 bins, no spare lane, and k past them: every lane is extracted
+    "more_k_than_bins": (8, 1024, 8, 8, 8, 130),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FUSED_CASES))
+@pytest.mark.parametrize("layout", ["masked", "ties", "sparse", "dead"])
+def test_fused_select_matches_two_pass_top_k(case, layout):
+    """The fused kernel's deferred select is bit-identical to the two-pass
+    kernel plus ``lax.top_k`` over all bin winners, in every bins-per-tile
+    class.  ``masked``: an all-tombstoned tile and a masked tail.  ``ties``:
+    every tile a copy of the first, so each bin winner ties with one in
+    every other tile and the lowest id must win.  ``sparse``: fewer live
+    bins than k, so most outputs are (-inf, -1) sentinels; ``dead``: every
+    row deleted, so all of them are."""
+    m, n, bin_size, block_m, block_n, k = _FUSED_CASES[case]
+    d = 128
+    rng = np.random.default_rng(n + bin_size + block_n)
+    q = jnp.asarray(rng.standard_normal((m, d)), jnp.float32)
+    db = rng.standard_normal((n, d)).astype(np.float32)
+    bias = np.zeros((1, n), np.float32)
+    if layout == "masked":
+        bias[0, block_n:2 * block_n] = _MASK      # a tombstoned tile
+        bias[0, n - block_n // 2 - 1:] = _MASK    # the masked tail
+    elif layout == "ties":
+        db = np.tile(db[:block_n], (n // block_n, 1))
+    else:
+        bias[0, (k // 2 if layout == "sparse" else 0) * bin_size:] = _MASK
+    tiles = dict(bin_size=bin_size, block_m=block_m, block_n=block_n)
+    got_v, got_i = partial_reduce_fused(
+        q, jnp.asarray(db), jnp.asarray(bias), k_scan=k, interpret=True,
+        **tiles,
+    )
+    # The oracle runs the query block the fused wrapper runs (padded to
+    # its clamped block_m), at that block_m.
+    bm = min(block_m, 8 * -(-m // 8))
+    q_pad = jnp.pad(q, ((0, bm * -(-m // bm) - m), (0, 0)))
+    want_v, want_i = _two_pass_top_k(
+        q_pad, jnp.asarray(db), jnp.asarray(bias), k,
+        **dict(tiles, block_m=bm),
+    )
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v[:m]))
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i[:m]))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -120,7 +120,7 @@ def test_legacy_oneshot_path_does_pad_database(data):
 @pytest.mark.parametrize("metric", ["mips", "l2", "cosine"])
 @pytest.mark.parametrize("storage", ["f32", "bf16", "int8", "int4"])
 def test_fused_select_matches_two_pass_oracle(data, metric, storage):
-    """The single-pass fused kernel (VMEM top-k carry) must be BIT-identical
+    """The single-pass fused kernel (VMEM winner buffer) must be BIT-identical
     to the two-pass scan→merge_topk composition on every metric × storage
     tier — the acceptance grid of the fused-select tentpole."""
     q, db = data
@@ -138,6 +138,38 @@ def test_fused_select_matches_two_pass_oracle(data, metric, storage):
     np.testing.assert_array_equal(
         np.asarray(fused.values), np.asarray(oracle.values)
     )
+
+
+@pytest.mark.parametrize("fused", [True, False])
+@pytest.mark.parametrize("storage", ["f32", "int8"])
+def test_fused_dispatches_count_their_select_mode(data, fused, storage):
+    """Every dispatch whose program runs the fused kernel counts its select
+    mode ("deferred") in ``FUSED_SELECT_COUNTS``, which the registry
+    exports and ``SearchServer.stats()`` reports; the two-pass program
+    counts nothing."""
+    from repro.search import telemetry
+    from repro.search.serve import SearchServer, ServeConfig, VirtualClock
+
+    telemetry.reset_all()
+    q, db = data
+    index = Index.build(
+        db,
+        spec=SearchSpec(metric="l2", k=K, backend="pallas", storage=storage,
+                        fused_select=fused, query_block=32),
+    )
+    index.search(q[:8])   # one block
+    index.search(q)       # two blocks, one streamed dispatch
+    server = SearchServer(index, ServeConfig(max_batch=8),
+                          clock=VirtualClock())
+    server.submit(np.asarray(q[:3]))
+    server.run_until_idle()
+    want = {"deferred": 3} if fused else {}
+    assert sum(DISPATCH_COUNTS.values()) == 3
+    assert dict(backends.FUSED_SELECT_COUNTS) == want
+    assert server.stats()["fused_select"] == want
+    prom = telemetry.export_prometheus()
+    assert ('repro_fused_select_dispatches_total{mode="deferred"} 3' in prom
+            ) == fused
 
 
 @pytest.mark.parametrize("fused", [True, False])

@@ -121,6 +121,46 @@ def test_block_m_escalates_off_the_memory_wall():
     assert p.attainable_flops < 0.9 * HARDWARE["tpu_v4"].peak_flops
 
 
+@pytest.mark.parametrize("n,d,metric,storage,tiles", [
+    # (block_m, block_n, k_scan, query_block) of the benchmark's
+    # sift1m-f32 and glove1.2m-int8 configurations
+    (1_000_000, 128, "l2", "f32", (256, 4096, 10, 4096)),
+    (1_183_514, 100, "cosine", "int8", (256, 2048, 20, 4096)),
+])
+def test_v5e_plans_charge_the_fused_winner_buffer(n, d, metric, storage,
+                                                  tiles):
+    """The fused kernel keeps every bin winner of a query block in VMEM;
+    the planner charges that buffer and the benchmark plans keep their
+    tiles."""
+    from repro.kernels.partial_reduce import winner_buffer_bytes
+    from repro.search.plan import _vmem_need
+
+    p = plan_search(n=n, d=d, k=10, metric=metric, recall_target=0.95,
+                    backend="pallas", device="tpu_v5e", storage=storage)
+    assert (p.block_m, p.block_n, p.k_scan, p.query_block) == tiles
+    db_bytes = 4 if storage == "f32" else 1
+    two_pass = _vmem_need(p.block_m, p.block_n, p.d_pad, 4, p.bin_size,
+                          db_bytes)
+    fused = _vmem_need(p.block_m, p.block_n, p.d_pad, 4, p.bin_size,
+                       db_bytes, fused_n=n)
+    lanes = round_up(round_up(n, p.block_n) // p.bin_size, 128)
+    assert fused - two_pass == winner_buffer_bytes(
+        p.block_m, round_up(n, p.block_n), p.bin_size
+    ) == 2 * 4 * p.block_m * lanes
+
+
+def test_block_m_shrinks_to_fit_a_large_winner_buffer():
+    """Small bins over a large database make the fused winner buffer the
+    largest thing in VMEM; the planner halves block_m until it fits."""
+    from repro.search.plan import _vmem_budget, _vmem_need
+
+    p = plan_search(n=1_280_000, d=128, k=500, metric="l2",
+                    recall_target=0.95, backend="pallas", device="tpu_v5e")
+    assert p.block_m < 256
+    assert _vmem_need(p.block_m, p.block_n, p.d_pad, 4, p.bin_size, 4,
+                      fused_n=p.n) <= _vmem_budget(HARDWARE["tpu_v5e"])
+
+
 def test_device_detection_resolves():
     assert detect_device() in HARDWARE  # live backend, whatever it is
     assert detect_device("cpu") == "cpu"

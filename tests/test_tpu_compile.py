@@ -62,9 +62,10 @@ def no_persistent_cache():
         compilation_cache.reset_cache()
 
 
-def _kernel_args(workload: str, storage: str, sharding):
+def _kernel_args(workload: str, storage: str, sharding, rows=None):
     """Planner-chosen tiles and packed operand shapes, as ``Index.build``
-    lays them out (``repro.search.packed._layout``) for one query block."""
+    lays them out (``repro.search.packed._layout``) for one query block,
+    or for a served batch of ``rows`` queries."""
     w = KNN_WORKLOADS[workload]
     p = plan_search(n=w.n, d=w.d, k=w.k, metric=w.metric,
                     recall_target=w.recall_target, backend="pallas",
@@ -78,7 +79,7 @@ def _kernel_args(workload: str, storage: str, sharding):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     operands = [
-        shaped((p.query_block, w.d), jnp.float32),
+        shaped((rows or p.query_block, w.d), jnp.float32),
         shaped((n_pad, d_pad // 2 if int4 else d_pad), _STORED[storage]),
         shaped((1, n_pad), jnp.float32),
     ]
@@ -100,6 +101,7 @@ def _assert_kernel_compiles(fn, operands):
     ("sift1m", "int8"),
     ("sift1m", "int4"),
     ("glove1.2m", "f32"),
+    ("glove1.2m", "int8"),
 ])
 def test_fused_kernel_compiles_at_planned_tiles(
     one_chip, no_persistent_cache, workload, storage
@@ -117,4 +119,17 @@ def test_two_pass_kernel_compiles_at_planned_tiles(
     _, operands, tiles = _kernel_args("sift1m", "f32", one_chip)
     _assert_kernel_compiles(
         lambda *ops: partial_reduce_packed(*ops, **tiles), operands
+    )
+
+
+@pytest.mark.parametrize("rows", [8, 32])
+def test_fused_kernel_compiles_for_served_buckets(
+    one_chip, no_persistent_cache, rows
+):
+    """A served micro-batch runs the same kernel with block_m clamped to
+    its bucket (``partial_reduce._effective_block_m``)."""
+    p, operands, tiles = _kernel_args("sift1m", "f32", one_chip, rows=rows)
+    _assert_kernel_compiles(
+        lambda *ops: partial_reduce_fused(*ops, k_scan=p.k_scan, **tiles),
+        operands,
     )
