@@ -19,7 +19,7 @@ import numpy as np
 
 import reference
 
-__all__ = ["compare", "judge"]
+__all__ = ["compare", "gather_rows", "judge"]
 
 EPS32 = float(np.finfo(np.float32).eps)
 
@@ -27,8 +27,6 @@ EPS32 = float(np.finfo(np.float32).eps)
 def compare(cfg: dict, queries, vals, ids, db, ref_ids) -> dict:
     """The numbers compared, for sampled rows: ``queries`` (S, D), the
     program's ``vals``/``ids`` (S, k) and the reference's ``ref_ids``."""
-    import jax.numpy as jnp
-
     k, n = cfg["k"], cfg["n"]
     ids = np.asarray(ids)
     vals = np.asarray(vals, np.float64)
@@ -37,8 +35,7 @@ def compare(cfg: dict, queries, vals, ids, db, ref_ids) -> dict:
     bad_rows = (~in_range).any(axis=1) | dup | (ids.shape[1] != k)
     hits = [len(set(a.tolist()) & set(b.tolist())) for a, b in zip(ids, ref_ids)]
     safe = np.where(in_range, ids, 0)
-    rows = np.asarray(jnp.take(db, jnp.asarray(safe.ravel()), axis=0))
-    rows = rows.reshape(*ids.shape, -1)
+    rows = gather_rows(db, safe.ravel()).reshape(*ids.shape, -1)
     exact, mag = reference.exact_scores(queries, rows, cfg["metric"])
     err = np.abs(vals - exact) / (EPS32 * np.maximum(mag, 1e-30))
     err = np.where(in_range, err, 0.0)
@@ -47,6 +44,25 @@ def compare(cfg: dict, queries, vals, ids, db, ref_ids) -> dict:
         "value_err": float(err.max()) if err.size else 0.0,
         "bad_ids": int(bad_rows.sum()),
     }
+
+
+def gather_rows(db, ids):
+    """Host copy of the rows ``ids`` of ``db``; a database sharded by rows
+    gives each id from the shard that holds it, on that shard's device."""
+    import jax.numpy as jnp
+
+    if not reference.sharded(db):
+        return np.asarray(jnp.take(db, jnp.asarray(ids), axis=0))
+    out = np.empty((len(ids), db.shape[1]), np.float32)
+    for shard in db.addressable_shards:
+        rows = shard.index[0]
+        lo = rows.start or 0
+        hi = db.shape[0] if rows.stop is None else rows.stop
+        mine = (ids >= lo) & (ids < hi)
+        if mine.any():
+            out[mine] = np.asarray(jnp.take(
+                shard.data, jnp.asarray(ids[mine] - lo), axis=0))
+    return out
 
 
 def judge(numbers: dict, limits: dict) -> dict:

@@ -10,6 +10,9 @@ the same whatever implements the search:
 - bytes: the stored database read once, the queries read, the (M, k)
   values and ids written, and for a quantized tier with an exact rescore
   the least gather that rescore needs, k full-precision rows per query.
+
+Over several chips the same work is spread over all of them: the least
+time divides it by the chips times each one's peaks.
 """
 from __future__ import annotations
 
@@ -31,8 +34,9 @@ def search_cost(cfg: dict, m: int) -> dict:
             "mxu_type": MXU_TYPE[storage]}
 
 
-def least_time(cost: dict, peaks: dict) -> tuple:
-    """(seconds, "compute" | "memory"): the larger of the two bounds."""
-    compute = cost["ops"] / peaks["flops"][cost["mxu_type"]]
-    memory = cost["bytes"] / peaks["hbm_bytes_per_s"]
+def least_time(cost: dict, peaks: dict, chips: int = 1) -> tuple:
+    """(seconds, "compute" | "memory"): the larger of the two bounds, with
+    the work spread over ``chips`` chips of these peaks."""
+    compute = cost["ops"] / (chips * peaks["flops"][cost["mxu_type"]])
+    memory = cost["bytes"] / (chips * peaks["hbm_bytes_per_s"])
     return (compute, "compute") if compute >= memory else (memory, "memory")

@@ -15,6 +15,11 @@ which ignores the precision flag, computes the same control.  The parts
 are rounded with ``lax.reduce_precision`` and kept in float32: a
 round trip through the bfloat16 type may be folded away by XLA, which
 then computes one pass where three were asked for.
+
+A database sharded by rows over several devices is searched where it
+lies: each shard's top-k on its own device, then the candidates of all
+shards merged on the host by value, the lower global id first among
+equal values, which is the order a single device's ``lax.top_k`` gives.
 """
 from __future__ import annotations
 
@@ -23,8 +28,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-__all__ = ["exact_scores", "top_k"]
+__all__ = ["exact_scores", "sharded", "top_k"]
 
 # Bytes of one (query chunk x database) score tile.
 TILE_BYTES = 1 << 30
@@ -68,20 +74,67 @@ def _chunk_top_k(q, db, *, k, metric, precision):
     raise ValueError(f"no reference for metric {metric!r}")
 
 
+def sharded(db) -> bool:
+    """Whether ``db`` is split by rows over several devices."""
+    sharding = getattr(db, "sharding", None)
+    return (isinstance(sharding, NamedSharding)
+            and len(sharding.device_set) > 1)
+
+
+@functools.lru_cache(maxsize=None)
+def _shard_top_k(mesh, axis, k: int, metric: str, precision: str):
+    """(queries, db) -> each shard's top-k, (shards, M, k), ids global."""
+
+    def local(q, rows):
+        v, i = _chunk_top_k(q, rows, k=k, metric=metric, precision=precision)
+        offset = jax.lax.axis_index(axis) * rows.shape[0]
+        return v[None], (i + offset)[None]
+
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(), P(axis, None)),
+                                 out_specs=(P(axis), P(axis))))
+
+
+def _merge(vals, ids, k: int, metric: str):
+    """The best ``k`` of candidate rows (M, C), by value (``l2`` ascending,
+    ``cosine`` descending), the lower id first among equal values."""
+    primary = vals if metric == "l2" else -vals
+    order = np.lexsort((ids, primary), axis=-1)[:, :k]
+    return (np.take_along_axis(vals, order, axis=-1),
+            np.take_along_axis(ids, order, axis=-1))
+
+
 def top_k(queries, db, k: int, metric: str, precision: str = "highest"):
     """(values, ids) as host arrays, (M, k), in query chunks whose score
-    tile stays under ``TILE_BYTES``."""
+    tile stays under ``TILE_BYTES`` on each device."""
     if precision not in ("highest", "high"):
         raise ValueError(f"precision must be 'highest' or 'high', got "
                          f"{precision!r}")
     queries = jnp.asarray(queries, jnp.float32)
-    chunk = max(1, TILE_BYTES // (4 * db.shape[0]))
+    if sharded(db):
+        spec = db.sharding
+        program = _shard_top_k(spec.mesh, spec.spec[0], k, metric, precision)
+        rows = db.addressable_shards[0].data.shape[0]
+        replicated = NamedSharding(spec.mesh, P())
+
+        def search(q):
+            v, i = program(jax.device_put(q, replicated), db)
+            v, i = np.asarray(v), np.asarray(i)   # (shards, m, k)
+            return _merge(np.concatenate(v, axis=-1),
+                         np.concatenate(i, axis=-1), k, metric)
+    else:
+        rows = db.shape[0]
+
+        def search(q):
+            v, i = _chunk_top_k(q, db, k=k, metric=metric,
+                                precision=precision)
+            return np.asarray(v), np.asarray(i)
+    chunk = max(1, TILE_BYTES // (4 * rows))
     vals, ids = [], []
     for start in range(0, queries.shape[0], chunk):
-        v, i = _chunk_top_k(queries[start:start + chunk], db, k=k,
-                            metric=metric, precision=precision)
-        vals.append(np.asarray(v))
-        ids.append(np.asarray(i))
+        v, i = search(queries[start:start + chunk])
+        vals.append(v)
+        ids.append(i)
     return np.concatenate(vals), np.concatenate(ids)
 
 

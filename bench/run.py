@@ -11,10 +11,14 @@ in ``bench/metrics/<metric>.py``.  A run:
 
 1. checks the device: a TPU whose ``device_kind`` has published peaks
    (``bench/peaks.py``) and as many chips as the cell asks for, else it
-   exits nonzero and prints no result.  The index is built on one chip
-   and sharded over none, so a cell that asks for more is refused too;
+   exits 2 and prints no result.  A cell on more chips than one needs a
+   configuration whose ``backend`` is ``"sharded"``;
 2. draws the database and the queries on the device from ``--seed``,
-   builds the ``Index`` and warms up the mix's shapes: ``setup_s``;
+   builds the ``Index`` and warms up the mix's shapes: ``setup_s``.  A
+   ``"sharded"`` configuration draws its rows straight into their shards
+   over a one-axis mesh of the cell's chips and builds the index as a
+   user of such a host would: ``Index.build(..., backend="sharded")``
+   then ``.shard(mesh, db_axis=DB_AXIS)``;
 3. runs the mix for ``--seconds`` (under the profiler with ``--trace 1``);
 4. reads the peak device memory, frees the program's state, and compares
    a sample of what the window answered with the plain reference
@@ -57,6 +61,8 @@ TRACE_DIR = os.path.join(BENCH, ".trace")
 SAMPLE_ROWS = 2000
 # Data tags folded into the seed's key.
 TAGS = {"database": 1, "queries": 2}
+# The mesh axis a sharded configuration splits its rows over.
+DB_AXIS = "db"
 
 
 class NoChip(RuntimeError):
@@ -140,15 +146,17 @@ def seed_key(seed: int):
 
 
 def make_draw(seed: int):
-    """draw(tag, shapes) -> standard Gaussian float32 arrays of ``shapes``,
-    made on the device in one jitted call from the seed and the tag."""
+    """draw(tag, shapes, sharding=None) -> standard Gaussian float32 arrays
+    of ``shapes``, made on the device in one jitted call from the seed and
+    the tag; with ``sharding`` each array is made in its shards, so no
+    device ever holds more than its own."""
     import jax
     import jax.numpy as jnp
     from jax.profiler import TraceAnnotation
 
     base = seed_key(seed)
 
-    def draw(tag, shapes):
+    def draw(tag, shapes, sharding=None):
         shapes = [tuple(s) for s in shapes]
 
         def gen(key):
@@ -156,8 +164,10 @@ def make_draw(seed: int):
             return tuple(jax.random.normal(k, s, jnp.float32)
                          for k, s in zip(keys, shapes))
 
+        placed = (jax.jit(gen) if sharding is None else
+                  jax.jit(gen, out_shardings=(sharding,) * len(shapes)))
         with TraceAnnotation("bench.draw"):
-            out = jax.jit(gen)(jax.random.fold_in(base, TAGS[tag]))
+            out = placed(jax.random.fold_in(base, TAGS[tag]))
             jax.block_until_ready(out)
         return list(out)
 
@@ -184,46 +194,63 @@ def log_served(readings: dict) -> None:
         f"{max((p for p, _ in pauses), default=0.0)!r} s")
 
 
-def check_device(cell: dict, require_chip: bool):
+def check_device(cell: dict, cfg: dict, require_chip: bool):
     """(devices the cell uses, their peaks); raises :class:`NoChip` or
-    ``peaks.UnknownDevice`` unless the chips are there."""
+    ``peaks.UnknownDevice`` unless the chips are there.  Off the chip
+    (``require_chip=False``) the devices may be the CPU's, as many as the
+    cell asks for."""
     import jax
 
     from repro.launch.compile_cache import enable_compile_cache
 
-    if cell["chips"] != 1:
+    if cell["chips"] > 1 and cfg["backend"] != "sharded":
         raise NoChip(f"cell {cell['name']} asks for {cell['chips']} chips; "
-                     f"the harness builds the index on one chip and shards "
-                     f"it over none")
+                     f"its configuration {cell['config']} holds the index "
+                     f"on one")
     cache_dir = enable_compile_cache()
     devices = jax.devices()
     platform, kind = devices[0].platform, devices[0].device_kind
     if require_chip:
         if platform != "tpu":
             raise NoChip(f"JAX found no TPU (platform {platform!r})")
-        if len(devices) < cell["chips"]:
-            raise NoChip(f"cell {cell['name']} needs {cell['chips']} chips, "
-                         f"found {len(devices)}")
         chip_peaks = peaks.peaks_for(kind)
     else:
         chip_peaks = peaks.PEAKS["TPU v5 lite"]
+    if len(devices) < cell["chips"]:
+        raise NoChip(f"cell {cell['name']} needs {cell['chips']} chips, "
+                     f"found {len(devices)}")
     used = devices[:cell["chips"]]
     log(f"device {platform} {kind!r} x{len(used)}; jax {jax.__version__}; "
         f"compile cache {cache_dir}")
     return used, chip_peaks
 
 
-def build(cfg: dict, mix: dict, seed: int):
-    """(database, index, client) with the client's shapes warmed up."""
+def build(cfg: dict, mix: dict, seed: int, devices):
+    """(database, index, client) with the client's shapes warmed up; a
+    ``"sharded"`` configuration's database and index are sharded over
+    ``devices``."""
     from repro.search import Index
 
     draw = make_draw(seed)
-    db = draw("database", [(cfg["n"], cfg["d"])])[0]
+    sharded = cfg["backend"] == "sharded"
+    if not sharded:
+        db = draw("database", [(cfg["n"], cfg["d"])])[0]
+    else:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((len(devices),), (DB_AXIS,), devices=devices)
+        db = draw("database", [(cfg["n"], cfg["d"])],
+                  sharding=NamedSharding(mesh, P(DB_AXIS, None)))[0]
     index = Index.build(
         db, metric=cfg["metric"], k=cfg["k"],
         recall_target=cfg["recall_target"], backend=cfg["backend"],
         storage=cfg["storage"], plan=cfg["plan"], cluster=cfg["cluster"],
     )
+    if sharded:
+        index = index.shard(mesh, db_axis=DB_AXIS)
+        log(f"rows per chip {[s.data.shape[0] for s in db.addressable_shards]}")
     plan = index.kernel_plan
     log(f"plan block_m {plan.block_m} block_n {plan.block_n} k_scan "
         f"{plan.k_scan or plan.k} query_block {plan.query_block} "
@@ -249,9 +276,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     import jax
     from jax.profiler import TraceAnnotation
 
-    used, chip_peaks = check_device(cell, require_chip)
+    used, chip_peaks = check_device(cell, cfg, require_chip)
     platform, kind = used[0].platform, used[0].device_kind
-    db, index, client = build(cfg, mix, seed)
+    db, index, client = build(cfg, mix, seed, used)
     setup_s = time.monotonic() - START
     log(f"setup_s {setup_s!r}")
 

@@ -31,8 +31,8 @@ def main(argv=None) -> int:
     ap.add_argument("--stop-ms", type=float, default=100.0)
     args = ap.parse_args(argv)
     _, cell, cfg, mix = run.load_cell(args.workload)
-    run.check_device(cell, True)
-    _, _, client = run.build(cfg, mix, args.seeds[0])
+    used, _ = run.check_device(cell, cfg, True)
+    _, _, client = run.build(cfg, mix, args.seeds[0], used)
     for rate in args.rates:
         p50s = []
         for seed in args.seeds:

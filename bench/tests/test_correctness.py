@@ -14,7 +14,8 @@ TINY = {"config": {"n": 4096, "queries": 256},
         "mix": {"rate_per_s": 3000, "query_pool_rows": 1024}}
 
 
-@pytest.mark.parametrize("config", ["sift1m-f32", "glove1.2m-int8"])
+@pytest.mark.parametrize("config", ["sift1m-f32", "glove1.2m-int8",
+                                    "deep50m-f32"])
 def test_control_at_high_precision_fails_value_err(config):
     """The reference at ``high`` (three bf16 passes) in the program's
     place fails; the reference at ``highest`` passes."""

@@ -44,6 +44,18 @@ def test_glove_int8_is_compute_bound_at_the_int8_peak():
     assert cost["bytes"] == want
 
 
+def test_least_time_on_four_chips_is_a_quarter_of_one():
+    cost = costs.search_cost(config("deep50m-f32"), 10_000)
+    assert cost["ops"] == 2 * 10_000 * 50_000_000 * 96
+    one, bound_one = costs.least_time(cost, V5E)
+    four, bound_four = costs.least_time(cost, V5E, chips=4)
+    assert bound_one == bound_four == "compute"
+    assert four == pytest.approx(one / 4)
+    assert four == pytest.approx(9.6e13 / (4 * 197e12))      # 0.122 s
+    # one-chip readings are what they were
+    assert costs.least_time(cost, V5E, chips=1) == (one, bound_one)
+
+
 def test_unknown_device_kind_is_an_error():
     with pytest.raises(peaks.UnknownDevice):
         peaks.peaks_for("TPU v4")

@@ -10,18 +10,20 @@ import run
 
 BENCH = run.BENCH
 CELLS = ["sift1m-f32.batch", "glove1.2m-int8.batch", "sift1m-f32.served"]
+# The four-chip cell rehearses on four virtual devices (test_sharded.py).
+SHARDED = ["deep50m-f32.sharded4"]
 TINY = {"config": {"n": 4096, "queries": 256},
         "mix": {"rate_per_s": 50, "query_pool_rows": 1024}}
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", CELLS + SHARDED)
 def test_command_line_off_the_chip_exits_nonzero_without_a_result(cell):
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     proc = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
          "--seed", "3", "--seconds", "1", "--trace", "0"],
         cwd=run.ROOT, env=env, capture_output=True, text=True, timeout=300)
-    assert proc.returncode != 0
+    assert proc.returncode == 2
     assert proc.stdout.strip() == ""
     assert "not measured" in proc.stderr
 
@@ -52,14 +54,15 @@ def test_tiny_rehearsal_of_each_mix(cell, traced):
     assert result["attempted"] > 0 and result["failed"] == 0
     assert list(result)[-1] == "checks"
     names = set(result["metrics"])
+    batch = run.load_cell(cell)[3]["kind"] == "closed_batch"
     if not traced:
-        want = ({"batch_qps", "setup_s"} if cell.endswith(".batch")
+        want = ({"batch_qps", "setup_s"} if batch
                 else {"served_p50_ms", "setup_s"})
         assert names == want
     else:
         # Off the chip the trace holds no device ops: only the metrics
         # that need none are read.
-        want = ({"recall.batch"} if cell.endswith(".batch")
+        want = ({"recall.batch"} if batch
                 else {"serve_host_ms.served", "batch_rows.served",
                       "transfer_max_ms.served", "latency_p99_ms.served"})
         assert names == want

@@ -1,6 +1,6 @@
 """Mixes and their clients are found by name, an open-loop schedule offers
-every seed the same work, and a cell on more chips than the harness
-shards over is refused."""
+every seed the same work, and a cell on more chips than one is refused
+unless its configuration shards the index."""
 import numpy as np
 import pytest
 
@@ -52,5 +52,7 @@ def test_single_row_mix_sends_one_row_per_request():
 
 
 def test_a_cell_on_more_chips_than_one_is_refused():
-    with pytest.raises(run.NoChip, match="shards"):
-        run.check_device({"name": "x.sharded4", "chips": 4}, False)
+    # a configuration that does not shard holds its index on one chip
+    with pytest.raises(run.NoChip, match="holds the index on one"):
+        run.check_device({"name": "x.sharded4", "config": "x", "chips": 4},
+                         {"name": "x", "backend": "auto"}, False)

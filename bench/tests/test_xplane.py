@@ -56,6 +56,19 @@ def test_merge_and_busy_over_two_chips():
     assert xplane.busy_ns(t, 0, 40) == 20      # averaged over the chips
 
 
+def test_named_op_time_is_a_union_averaged_over_two_chips():
+    # chip 0: an all-gather's start [0, 10) and done [5, 20) overlap, a
+    # scan [20, 50); chip 1: a fused all-gather [0, 40)
+    ops = [(0, 10, "all-gather-start.1", False, 0),
+           (5, 20, "all-gather-done.1", False, 0),
+           (20, 50, "fusion.3", False, 0), (0, 40, "all-gather.2", False, 1)]
+    t = xplane.Trace(ops, [], [0, 1])
+    gathers = lambda name: "all-gather" in name  # noqa: E731
+    assert xplane.op_ns(t, 0, 100, gathers) == (20 + 40) // 2
+    assert xplane.op_ns(t, 10, 30, gathers) == (10 + 20) // 2
+    assert xplane.op_ns(t, 0, 100, lambda name: False) == 0
+
+
 @pytest.fixture(scope="module")
 def recorded():
     path = os.path.join(DATA, "small.xplane.pb")

@@ -22,7 +22,7 @@ import glob
 import os
 
 __all__ = ["Trace", "busy_ns", "find", "idle_gaps", "idle_percent", "load",
-           "merge", "self_ns", "top_ops", "window"]
+           "merge", "op_ns", "self_ns", "top_ops", "window"]
 
 HOST_PREFIXES = ("bench.", "serve.dispatch")
 TRANSFERS = ("np.asarray(jax.Array)", "DevicePutWithSharding")
@@ -103,9 +103,11 @@ def clip(intervals, lo: int, hi: int):
             if e > lo and s < hi]
 
 
-def _union_ns(trace: Trace, lo: int, hi: int, dev, custom_only: bool) -> int:
-    iv = [(s, e) for s, e, _, c, d in trace.ops
-          if d == dev and (c or not custom_only)]
+def _union_ns(trace: Trace, lo: int, hi: int, dev, keep) -> int:
+    """Nanoseconds of ``[lo, hi)`` in which an op of chip ``dev`` that
+    ``keep(name, custom)`` keeps ran."""
+    iv = [(s, e) for s, e, name, c, d in trace.ops
+          if d == dev and keep(name, c)]
     return sum(e - s for s, e in merge(clip(iv, lo, hi)))
 
 
@@ -115,9 +117,17 @@ def busy_ns(trace: Trace, lo: int, hi: int, *, custom=None) -> int:
     ran; ``custom=False`` only the busy time in which none did."""
     total = 0
     for dev in trace.devices:
-        busy = _union_ns(trace, lo, hi, dev, False)
-        kernels = _union_ns(trace, lo, hi, dev, True)
+        busy = _union_ns(trace, lo, hi, dev, lambda name, c: True)
+        kernels = _union_ns(trace, lo, hi, dev, lambda name, c: c)
         total += {None: busy, True: kernels, False: busy - kernels}[custom]
+    return total // max(1, len(trace.devices))
+
+
+def op_ns(trace: Trace, lo: int, hi: int, named) -> int:
+    """Nanoseconds of ``[lo, hi)`` in which an op whose name satisfies
+    ``named(name)`` ran, averaged over the chips."""
+    total = sum(_union_ns(trace, lo, hi, dev, lambda name, c: named(name))
+                for dev in trace.devices)
     return total // max(1, len(trace.devices))
 
 
