@@ -60,6 +60,7 @@ __all__ = [
     "partial_reduce_fused_pallas",
     "partial_reduce_packed",
     "partial_reduce_pallas",
+    "scan_operands",
     "SELECT_MODE",
     "winner_buffer_bytes",
 ]
@@ -123,8 +124,9 @@ def partial_reduce_packed(
     ``repro.search.packed``.  Only the (m, d) query block is padded here,
     so repeated searches against the same database perform zero
     database-sized copies.  ``database`` may be stored in a reduced-
-    precision tier (bf16/int8/int4 — dequantized tile-locally in VMEM, so
-    HBM streams the reduced bytes); ``scale`` carries the per-row scale,
+    precision tier, so HBM streams the reduced bytes: bf16 rows meet
+    queries rounded to bf16 (:func:`_scan_queries`), int8/int4 codes are
+    cast tile-locally in VMEM; ``scale`` carries the per-row scale,
     and ``int4_packed`` marks a two-codes-per-byte database whose logical
     width is twice its stored width.
     Returns (values, indices) with the query padding already stripped:
@@ -134,6 +136,7 @@ def partial_reduce_packed(
     d_pad = database.shape[1] * (2 if int4_packed else 1)
     if d > d_pad:
         raise ValueError(f"query dim {d} exceeds packed dim {d_pad}")
+    queries = _scan_queries(queries, database)
     bm = _effective_block_m(m, block_m, queries.dtype)
     m_pad = round_up(m, bm)
     q = jnp.pad(queries, ((0, m_pad - m), (0, d_pad - d)))
@@ -172,6 +175,7 @@ def partial_reduce_fused(
     d_pad = database.shape[1] * (2 if int4_packed else 1)
     if d > d_pad:
         raise ValueError(f"query dim {d} exceeds packed dim {d_pad}")
+    queries = _scan_queries(queries, database)
     bm = _effective_block_m(m, block_m, queries.dtype)
     m_pad = round_up(m, bm)
     q = jnp.pad(queries, ((0, m_pad - m), (0, d_pad - d)))
@@ -183,17 +187,55 @@ def partial_reduce_fused(
     return vals[:m], idxs[:m]
 
 
-def _load_db_tile(x_ref, q_dtype, int4_packed: bool):
-    """VMEM view of one database tile in the compute dtype.
+def _scan_queries(queries: jnp.ndarray, database: jnp.ndarray) -> jnp.ndarray:
+    """The queries as the scan dot takes them.
 
-    For the packed int4 tier the HBM stream carried two two's-complement
-    nibbles per byte; unpack them here (arithmetic shifts sign-extend) so
-    only the halved byte count ever crossed the memory wall.  Byte j holds
-    logical column 2j in its low nibble and 2j+1 in its high nibble —
-    matching ``quant.pack_int4_rows``.  The tile is unpacked as
-    ``[all low nibbles | all high nibbles]`` (two lane-aligned halves, no
-    interleave) and the query columns are permuted to match outside the
-    kernel (:func:`_int4_query_order`), so the dot product is unchanged.
+    bf16 rows meet bf16 queries: the (M, D) block is rounded once, outside
+    the grid loop, so each tile goes to the MXU as stored (one bf16 pass,
+    f32 accumulation) with no per-tile cast.  Every other tier's queries
+    are returned as given.
+    """
+    if database.dtype == jnp.bfloat16:
+        return queries.astype(jnp.bfloat16)
+    return queries
+
+
+def scan_operands(q_dtype, db_dtype, int4_packed: bool = False) -> str:
+    """The form the scan dot's operands take, for queries of ``q_dtype``
+    against stored rows of ``db_dtype``: ``"f32_highest"`` (f32 x f32 at
+    full precision), ``"bf16"`` (bf16 x bf16), or ``"int8_upcast"`` /
+    ``"int4_upcast"`` (integer codes cast to the query dtype in VMEM).
+
+    >>> import jax.numpy as jnp
+    >>> scan_operands(jnp.float32, jnp.float32), scan_operands(
+    ...     jnp.float32, jnp.bfloat16)
+    ('f32_highest', 'bf16')
+    >>> scan_operands(jnp.float32, jnp.int8, int4_packed=True)
+    'int4_upcast'
+    """
+    if int4_packed:
+        return "int4_upcast"
+    if jnp.dtype(db_dtype) == jnp.int8:
+        return "int8_upcast"
+    if jnp.bfloat16 in (jnp.dtype(q_dtype), jnp.dtype(db_dtype)):
+        return "bf16"
+    return "f32_highest"
+
+
+def _load_db_tile(x_ref, q_dtype, int4_packed: bool):
+    """VMEM view of one database tile in the query dtype.
+
+    f32 and bf16 tiles go to the MXU as stored (bf16 rows meet queries
+    rounded by :func:`_scan_queries`).  int8 codes are cast to the query
+    dtype here; their per-row scales apply to the scores.  For the packed
+    int4 tier the HBM stream carried two two's-complement nibbles per
+    byte; unpack them here (arithmetic shifts sign-extend) so only the
+    halved byte count ever crossed the memory wall.  Byte j holds logical
+    column 2j in its low nibble and 2j+1 in its high nibble — matching
+    ``quant.pack_int4_rows``.  The tile is unpacked as ``[all low nibbles |
+    all high nibbles]`` (two lane-aligned halves, no interleave) and the
+    query columns are permuted to match outside the kernel
+    (:func:`_int4_query_order`), so the dot product is unchanged.
     """
     x = x_ref[...]
     if int4_packed:
@@ -202,8 +244,6 @@ def _load_db_tile(x_ref, q_dtype, int4_packed: bool):
         hi = xb >> 4
         x = jnp.concatenate([lo, hi], axis=1)
     if x.dtype != q_dtype:
-        # Reduced-precision storage tier: dequantize the tile in VMEM
-        # before it hits the MXU (per-row scales apply to the scores).
         x = x.astype(q_dtype)
     return x
 
@@ -225,8 +265,9 @@ def _tile_winners(q_ref, x_ref, scale_ref, bias_ref,
     x = _load_db_tile(x_ref, q.dtype, int4_packed)
     # MXU: one (block_m, d) x (d, block_n) matmul, f32 accumulation.  The
     # f32 tier's scores are its results, so its dot runs at full f32
-    # precision; a quantized tier's scan only ranks candidates for the
-    # exact rescore and keeps the default.
+    # precision.  A quantized tier's scan only ranks candidates for the
+    # exact rescore: bf16 x bf16 is one MXU pass as given, and the int
+    # tiers' upcast tiles keep the default precision.
     exact = x_ref.dtype == jnp.float32 and q.dtype == jnp.float32
     scores = jax.lax.dot_general(
         q,
@@ -465,9 +506,10 @@ def partial_reduce_pallas(
 
     Shapes must already satisfy the tiling contract — use
     ``repro.kernels.ops`` for the padding/planning front-end.  ``database``
-    may be a reduced-precision storage tier (bf16/int8/int4); ``scale`` is
-    the scaled tiers' per-row dequantization scale, applied to the score
-    tile in VMEM.
+    may be a reduced-precision storage tier (bf16/int8/int4; bf16 rows are
+    scanned against queries rounded to bf16); ``scale`` is the scaled
+    tiers' per-row dequantization scale, applied to the score tile in
+    VMEM.
 
     The kernel writes its bin winners as (n // block_n, m, bins_per_block)
     — one (block_m, bins_per_block) block per grid step, whose last dim is
@@ -477,6 +519,7 @@ def partial_reduce_pallas(
     """
     _validate_tiling(queries, database, block_m=block_m, block_n=block_n,
                      bin_size=bin_size, int4_packed=int4_packed)
+    queries = _scan_queries(queries, database)
     if int4_packed:
         queries = _int4_query_order(queries)
     m, d = queries.shape
@@ -553,6 +596,7 @@ def partial_reduce_fused_pallas(
                      bin_size=bin_size, int4_packed=int4_packed)
     if k_scan <= 0:
         raise ValueError(f"k_scan must be positive, got {k_scan}")
+    queries = _scan_queries(queries, database)
     if int4_packed:
         queries = _int4_query_order(queries)
     m, d = queries.shape

@@ -41,6 +41,7 @@ from repro.kernels.partial_reduce import (
     partial_reduce_fused,
     partial_reduce_packed,
     partial_reduce_pallas,
+    scan_operands,
 )
 from repro.search.metrics import get_metric
 from repro.search.plan import _plan_query_block
@@ -64,6 +65,7 @@ __all__ = [
     "TRACE_COUNTS",
     "DISPATCH_COUNTS",
     "FUSED_SELECT_COUNTS",
+    "SCAN_OPERAND_COUNTS",
     "CompileCache",
     "count_dispatch",
     "cluster_search",
@@ -99,6 +101,11 @@ DISPATCH_COUNTS = telemetry.AtomicCounter()
 # kernel (``count_dispatch``).
 FUSED_SELECT_COUNTS = telemetry.AtomicCounter()
 
+# scan operand form (``kernels.partial_reduce.scan_operands``: "f32_highest",
+# "bf16", "int8_upcast", "int4_upcast") -> dispatches whose program runs the
+# Pallas scan with its dot given that form (``count_dispatch``).
+SCAN_OPERAND_COUNTS = telemetry.AtomicCounter()
+
 telemetry.registry().register_counter_dict(
     "repro_traces_total", TRACE_COUNTS, "backend",
     "jit traces per backend (steady state: zero growth)",
@@ -111,16 +118,24 @@ telemetry.registry().register_counter_dict(
     "repro_fused_select_dispatches_total", FUSED_SELECT_COUNTS, "mode",
     "dispatches that run the fused scan→select kernel, by select mode",
 )
+telemetry.registry().register_counter_dict(
+    "repro_scan_operand_dispatches_total", SCAN_OPERAND_COUNTS, "operand",
+    "dispatches that run the Pallas scan, by the operand form of its dot",
+)
 
 
-def count_dispatch(backend: str, spec, clustered: bool) -> None:
-    """Count one device dispatch of ``spec`` on ``backend``, and the fused
-    kernel's select mode when the dispatched program runs it: the Pallas
-    dense path with ``fused_select`` on (a rescore implies
-    ``aggregate_to_topk``)."""
+def count_dispatch(backend: str, spec, pk, q_dtype) -> None:
+    """Count one device dispatch of ``spec`` on ``backend`` over the
+    packed state ``pk`` with queries of ``q_dtype``.  When the dispatched
+    program runs the Pallas scan (the dense Pallas path), also count the
+    operand form its dot is given, and the fused kernel's select mode when
+    ``fused_select`` is on (a rescore implies ``aggregate_to_topk``)."""
     DISPATCH_COUNTS.inc(backend)
-    if (backend == "pallas" and not clustered
-            and spec.fused_select_enabled and spec.aggregate_to_topk):
+    if backend != "pallas" or pk.cluster is not None:
+        return
+    SCAN_OPERAND_COUNTS.inc(
+        scan_operands(q_dtype, pk.db.dtype, pk.storage == "int4"))
+    if spec.fused_select_enabled and spec.aggregate_to_topk:
         FUSED_SELECT_COUNTS.inc(SELECT_MODE)
 
 

@@ -794,7 +794,7 @@ class Index:
         fn = self._cache.get(
             key, lambda: self._build_block_fn(backend, pk, batch_axis)
         )
-        backends.count_dispatch(backend, self.spec, pk.cluster is not None)
+        backends.count_dispatch(backend, self.spec, pk, q.dtype)
         return fn(q, *pk.operands())
 
     def _build_host_searcher(self) -> hosttierlib.HostTierSearcher:
@@ -854,7 +854,7 @@ class Index:
         fn = self._cache.get(
             key, lambda: self._build_stream_fn(backend, pk, batch_axis)
         )
-        backends.count_dispatch(backend, self.spec, pk.cluster is not None)
+        backends.count_dispatch(backend, self.spec, pk, blocks.dtype)
         vals, idxs = fn(blocks, *pk.operands())
         k = vals.shape[-1]
         return SearchResult(

@@ -794,6 +794,10 @@ def plan_search(
     # to spec.dtype before preparing), so its rows stream at dbytes; the
     # quantized tiers stream their own narrower width.
     sbytes = dbytes if storage == "f32" else quant.storage_bytes(storage)
+    # The scan's query operand: bf16 rows are scanned against queries
+    # rounded to bf16 (kernels.partial_reduce._scan_queries), so that tier's
+    # query tile has bf16 bytes and sublanes, and no tile is cast in VMEM.
+    qbytes = 2 if storage == "bf16" else dbytes
     if storage == "int4" and backend != "pallas":
         # Only the Pallas packed layout stores two nibbles per byte; every
         # other backend scores the canonical int8-held codes, so its
@@ -838,7 +842,7 @@ def plan_search(
     )
     d_pad = round_up(d, 128)
     bm, bn = _plan_tiles(
-        n, d_pad, bins.bin_size, m, dbytes, hw,
+        n, d_pad, bins.bin_size, m, qbytes, hw,
         block_m=block_m, max_block_n=max_block_n, db_bytes=sbytes,
         fused_n=n if backend == "pallas" else 0,
     )
@@ -866,7 +870,7 @@ def plan_search(
         if block_m is None:
             bm = _escalate_block_m(
                 bm, bn, m_eff, bins.padded_n, d_pad, bins.num_bins, c,
-                dbytes, bins.bin_size, hw, db_bytes=sbytes, k_scan=ks,
+                qbytes, bins.bin_size, hw, db_bytes=sbytes, k_scan=ks,
             )
         # The kernel clamps its query tile to the sublane-rounded batch
         # (kernels.partial_reduce._effective_block_m), so a 1-row search
@@ -874,12 +878,12 @@ def plan_search(
         # the kernel actually runs, then price the fused single-pass
         # program: the database streamed once per query block plus the
         # O(M·k_scan) result, with no score-tile HBM round trip.
-        sublane_q = _SUBLANE.get(dbytes, 8)
+        sublane_q = _SUBLANE.get(qbytes, 8)
         bm_eff = min(bm, max(sublane_q, round_up(max(m_eff, 1), sublane_q)))
         m_pad = round_up(max(m_eff, 1), bm_eff)
         cost = partial_reduce_fused_cost(
             m_pad, bins.padded_n, d_pad, ks,
-            cops_per_dot=c, block_rows=bm_eff, dtype_bytes=dbytes,
+            cops_per_dot=c, block_rows=bm_eff, dtype_bytes=qbytes,
             db_bytes=sbytes, block_n=bn,
             bins_per_block=max(1, bn // bins.bin_size),
         )

@@ -9,7 +9,9 @@ directly sets where the roofline knee lands.  This module owns the
     state, kernels and planner behave bit-identically to before this
     subsystem existed).
   * ``"bf16"`` — 2 bytes/element.  The scan matmul consumes the bf16 rows
-    directly (f32 accumulation), halving database HBM traffic.
+    as stored against the queries rounded to bf16 once per query block:
+    one bf16 x bf16 MXU pass with f32 accumulation, no per-tile cast, and
+    half the database HBM traffic.
   * ``"int8"`` — 1 byte/element with a per-row symmetric scale
     (``row ≈ scale * int8``), quartering database HBM traffic.
   * ``"int4"`` — 0.5 bytes/element with a per-row symmetric scale
@@ -56,6 +58,17 @@ band 16x to a ~7 % relative error, so its in-band rival budget doubles
 again) under a bounded near-tie density — they are deliberately
 conservative, and ``tests/test_recall_guarantee.py`` validates the
 end-to-end recall empirically with a Hoeffding margin.
+
+The bf16 scan rounds the queries as well as the rows, each to the same
+unit roundoff ``u = 2^-8``.  The budget still holds: the bias is computed
+from the stored rows, so a row's rounding ``dx`` enters the l2 scan score
+as ``<q - x, dx>`` and the query's ``dq`` as ``<dq, x>``, two independent
+terms, and the score's rms error grows by about a tenth over rounding the
+rows alone (0.0377 -> 0.0419 score units for Gaussian rows at D = 128,
+N = 2^16 and 2^18).  Against the budget's ``ceil(K/2) = 5`` in-band
+rivals at K = 10, the count that matters — truly-worse rows whose scan
+score passes a true top-K entry's — is 0.006 per entry there, never more
+than 1 (``tests/test_quant.py`` counts it).
 
 Nothing here imports the rest of ``repro.search`` — the metric registry,
 packed state, planner and backends all build *on* these primitives.

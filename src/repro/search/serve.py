@@ -1289,6 +1289,8 @@ class SearchServer:
             # Process-wide, like DISPATCH_COUNTS: dispatches that ran the
             # fused scan→select kernel, by its select mode.
             "fused_select": dict(backends.FUSED_SELECT_COUNTS),
+            # ... and by the operand form of the Pallas scan's dot.
+            "scan_operands": dict(backends.SCAN_OPERAND_COUNTS),
         }
         live = out["dispatched_rows"] + out["padded_rows"]
         out["occupancy"] = out["dispatched_rows"] / live if live else 0.0

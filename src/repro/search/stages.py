@@ -115,14 +115,21 @@ def score_rows(
     """Streamed biased-MIPS score tile: ``q @ db.T (* scale) + bias``.
 
     ``q`` must already be metric-prepared; ``database`` holds the stored
-    rows of any tier (bf16/int8 rows score through ``scale``, the int8
-    per-row dequantization scale).  ``row_bias`` is the fused bias row —
-    adding it *after* the scale keeps quantized scan scores internally
-    consistent (the bias is computed from the stored values).
+    rows of any tier (int8 rows score through ``scale``, the per-row
+    dequantization scale).  bf16 rows are scored against the queries
+    rounded to bf16, with f32 accumulation, as the Pallas kernel scores
+    them (``kernels.partial_reduce._scan_queries``), so both backends rank
+    by the same scores.  ``row_bias`` is the fused bias row — adding it
+    *after* the scale keeps quantized scan scores internally consistent
+    (the bias is computed from the stored values).
     """
-    exact = database.dtype == jnp.float32
-    scores = jnp.einsum("ik,jk->ij", q, database,
-                        precision=EXACT if exact else None)
+    if database.dtype == jnp.bfloat16 and q.dtype == jnp.float32:
+        scores = jnp.einsum("ik,jk->ij", q.astype(jnp.bfloat16), database,
+                            preferred_element_type=jnp.float32)
+    else:
+        exact = database.dtype == jnp.float32
+        scores = jnp.einsum("ik,jk->ij", q, database,
+                            precision=EXACT if exact else None)
     if scale is not None:
         scores = scores * scale[None, :]
     if row_bias is not None:

@@ -9,6 +9,7 @@ from repro.core.knn import exact_mips
 from repro.kernels.ops import l2_topk, mips_topk
 from repro.kernels.partial_reduce import (
     partial_reduce_fused,
+    partial_reduce_packed,
     partial_reduce_pallas,
 )
 from repro.kernels.ref import partial_reduce_ref
@@ -189,3 +190,72 @@ def test_kernel_serves_knn_attention_selection():
     agree = (np.asarray(idx_jnp).reshape(b * h, 32) ==
              np.asarray(idx_kernel)).mean()
     assert agree > 0.95  # same plan; near-ties may differ in f32 vs kernel
+
+
+def _tier_operands(storage, n, d):
+    """(f32 queries, stored rows, per-row scale or None, the stored rows'
+    canonical codes or values) for one storage tier, seeded."""
+    from repro.search import quant
+
+    q = jax.random.normal(jax.random.PRNGKey(7), (32, d), jnp.float32)
+    rows = jax.random.normal(jax.random.PRNGKey(8), (n, d), jnp.float32)
+    stored, scale = quant.quantize_rows(rows, storage)
+    canonical = stored
+    if storage == "int4":
+        stored = quant.pack_int4_rows(stored)
+    return q, stored, None if scale is None else scale[None, :], canonical
+
+
+def _plain_scan_scores(q, canonical, scale, bias, storage):
+    """The scan's scores in plain jnp, in the operand form each tier's dot
+    is given: f32 x f32 at full precision; bf16 x bf16 with f32
+    accumulation; integer codes cast to f32 at the default precision
+    (int4 with its columns in the kernel's [even | odd] order)."""
+    if storage == "f32":
+        s = jnp.einsum("ik,jk->ij", q, canonical,
+                       precision=jax.lax.Precision.HIGHEST)
+    elif storage == "bf16":
+        s = jnp.einsum("ik,jk->ij", q.astype(jnp.bfloat16), canonical,
+                       preferred_element_type=jnp.float32)
+    else:
+        x = canonical.astype(jnp.float32)
+        if storage == "int4":
+            q = jnp.concatenate([q[:, 0::2], q[:, 1::2]], axis=1)
+            x = jnp.concatenate([x[:, 0::2], x[:, 1::2]], axis=1)
+        s = jnp.einsum("ik,jk->ij", q, x)
+    if scale is not None:
+        s = s * scale
+    return s + bias
+
+
+@pytest.mark.parametrize("storage", ["f32", "bf16", "int8", "int4"])
+def test_each_tier_scans_with_its_stated_operands(storage):
+    """Each tier's bin winners are, bit for bit, those of a plain jnp dot
+    of its stated operand form: the bf16 tier a bf16 x bf16 -> f32 dot of
+    the queries rounded to bf16 and the rows as stored; the f32, int8 and
+    int4 tiers the operands and precision they always had."""
+    n, d, bin_size = 2048, 256, 64
+    q, stored, scale, canonical = _tier_operands(storage, n, d)
+    bias = -0.5 * jax.random.uniform(jax.random.PRNGKey(9), (1, n))
+    got_v, got_i = partial_reduce_packed(
+        q, stored, bias, scale, bin_size=bin_size, block_m=16, block_n=512,
+        interpret=True, int4_packed=storage == "int4",
+    )
+    # Scored tile by tile at the kernel's tile shapes, each tile in one
+    # program as the kernel's body is: the CPU's dot sums a row in an
+    # order that depends on the operand shapes, and a program may fuse
+    # the scale and the bias into one multiply-add.
+    scores = jnp.concatenate([
+        jnp.concatenate([
+            jax.jit(_plain_scan_scores, static_argnums=4)(
+                q[i:i + 16], canonical[j:j + 512],
+                None if scale is None else scale[:, j:j + 512],
+                bias[:, j:j + 512], storage)
+            for j in range(0, n, 512)], axis=1)
+        for i in range(0, q.shape[0], 16)])
+    binned = scores.reshape(q.shape[0], n // bin_size, bin_size)
+    want_i = (jnp.argmax(binned, axis=-1)
+              + jnp.arange(n // bin_size) * bin_size)
+    np.testing.assert_array_equal(np.asarray(got_v),
+                                  np.asarray(jnp.max(binned, axis=-1)))
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))

@@ -172,6 +172,45 @@ def test_fused_dispatches_count_their_select_mode(data, fused, storage):
             ) == fused
 
 
+@pytest.mark.parametrize("backend,storage,want", [
+    ("pallas", "f32", {"f32_highest": 3}),
+    ("pallas", "bf16", {"bf16": 3}),
+    ("pallas", "int8", {"int8_upcast": 3}),
+    ("pallas", "int4", {"int4_upcast": 3}),
+    ("xla", "bf16", {}),
+])
+def test_pallas_dispatches_count_their_scan_operands(data, backend, storage,
+                                                     want):
+    """Every dispatch whose program runs the Pallas scan counts the
+    operand form its dot is given in ``SCAN_OPERAND_COUNTS``, which the
+    registry exports and ``SearchServer.stats()`` reports; the XLA scan
+    counts nothing."""
+    from repro.search import telemetry
+    from repro.search.serve import SearchServer, ServeConfig, VirtualClock
+
+    telemetry.reset_all()
+    q, db = data
+    index = Index.build(
+        db,
+        spec=SearchSpec(metric="l2", k=K, backend=backend, storage=storage,
+                        query_block=32),
+    )
+    index.search(q[:8])   # one block
+    index.search(q)       # two blocks, one streamed dispatch
+    server = SearchServer(index, ServeConfig(max_batch=8),
+                          clock=VirtualClock())
+    server.submit(np.asarray(q[:3]))
+    server.run_until_idle()
+    assert sum(DISPATCH_COUNTS.values()) == 3
+    assert dict(backends.SCAN_OPERAND_COUNTS) == want
+    assert server.stats()["scan_operands"] == want
+    prom = telemetry.export_prometheus()
+    for operand, count in want.items():
+        assert (f'repro_scan_operand_dispatches_total{{operand="{operand}"}}'
+                f" {count}") in prom
+    assert ("repro_scan_operand_dispatches_total{" in prom) == bool(want)
+
+
 @pytest.mark.parametrize("fused", [True, False])
 @pytest.mark.parametrize("storage", ["f32", "int8", "int4"])
 def test_masked_winners_are_sentinels_not_phantom_duplicates(fused, storage):

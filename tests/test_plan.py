@@ -336,6 +336,20 @@ def test_plan_inherits_database_dtype():
     assert index.kernel_plan.block_m % 8 == 0
 
 
+@pytest.mark.parametrize("m", [1, 24])
+def test_bf16_tier_plans_bf16_query_tiles(m):
+    """The bf16 tier's kernel takes its queries rounded to bf16, so even
+    with f32 queries its query tile keeps the bf16 sublane floor of 16
+    rows, and no tile is charged a VMEM cast (the same tile as an index
+    whose compute dtype is bf16)."""
+    kw = dict(n=1 << 16, d=128, k=10, m=m, backend="pallas",
+              device="tpu_v5e", storage="bf16")
+    p = plan_search(**kw)
+    assert p.block_m % 16 == 0
+    both = plan_search(dtype="bfloat16", **kw)
+    assert (p.block_m, p.block_n) == (both.block_m, both.block_n)
+
+
 def test_replans_preserve_recall_accounting_override():
     """Growth re-plans must keep reduction_input_size_override, matching
     the packed relayout's bin math (paper §7 accounting)."""

@@ -94,6 +94,33 @@ def test_scan_k_overfetch():
         quant.scan_k("fp4", 10)
 
 
+def test_bf16_scan_promotes_fewer_rivals_than_its_budget():
+    """The bf16 scan rounds the queries as well as the rows (one bf16 x
+    bf16 pass).  The over-fetch budget T(bf16) = ceil(K/2) counts the
+    truly-worse rows whose scan score may pass a true top-K entry's; with
+    both roundings that count stays far under it (``quant``'s docstring)."""
+    from repro.search.stages import score_rows
+
+    n, d, m, k = 16384, 128, 32, 10
+    rows = jax.random.normal(jax.random.PRNGKey(40), (n, d))
+    q = jax.random.normal(jax.random.PRNGKey(41), (m, d))
+    stored, _ = quant.quantize_rows(rows, "bf16")
+    held = np.asarray(stored, np.float64)
+    scan = np.asarray(score_rows(q, stored, jnp.asarray(
+        -0.5 * (held * held).sum(1), jnp.float32)), np.float64)
+    x, qe = np.asarray(rows, np.float64), np.asarray(q, np.float64)
+    exact = qe @ x.T - 0.5 * (x * x).sum(1)
+    rivals = []
+    for i in range(m):
+        top = np.argsort(-exact[i])[:k]
+        worse = np.ones(n, bool)
+        worse[top] = False
+        rivals += [int(np.sum(worse & (scan[i] > scan[i, e]))) for e in top]
+    budget = quant.scan_k("bf16", k) - k
+    assert max(rivals) <= budget
+    assert np.mean(rivals) <= 0.1 * budget
+
+
 def test_int4_per_row_error_bound():
     rows = jax.random.normal(jax.random.PRNGKey(5), (32, 64)) * jnp.arange(
         1, 33

@@ -155,6 +155,10 @@ QUANT_CORNERS = [
     ("l2", "xla", "int8", 10, 0.95, 4, 256),
     ("cosine", "xla", "int8", 4, 0.99, 4, 256),
     ("l2", "pallas", "bf16", 16, 0.90, 2, 128),
+    # sift1m-bf16's k and target: queries and rows both rounded to bf16
+    # (one bf16 x bf16 pass) under T(bf16) = ceil(K/2).
+    ("l2", "xla", "bf16", 10, 0.95, 4, 256),
+    ("l2", "pallas", "bf16", 10, 0.95, 2, 128),
     ("mips", "pallas", "int8", 8, 0.90, 2, 128),
     # int4: half-byte rows, T(int4)=2K over-fetch (quant.scan_k) — the
     # widest-error tier the two-pass guarantee must still absorb.

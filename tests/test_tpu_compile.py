@@ -133,3 +133,27 @@ def test_fused_kernel_compiles_for_served_buckets(
         lambda *ops: partial_reduce_fused(*ops, k_scan=p.k_scan, **tiles),
         operands,
     )
+
+
+@pytest.mark.parametrize("rows", [None, 8, 32])
+def test_bf16_tier_gives_the_kernel_bf16_queries(
+    one_chip, no_persistent_cache, rows
+):
+    """sift1m at bf16, for a query block and for served buckets: the kernel
+    takes the queries rounded to bf16 (its first operand), so its dot is
+    bf16 x bf16, and a bucket of 8 rows runs at the bf16 floor of 16."""
+    import re
+
+    p, operands, tiles = _kernel_args("sift1m", "bf16", one_chip, rows=rows)
+    assert p.block_m % 16 == 0
+    compiled = jax.jit(
+        lambda *ops: partial_reduce_fused(*ops, k_scan=p.k_scan, **tiles)
+    ).lower(*operands).compile().as_text()
+    kernel = [line for line in compiled.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernel) == 1
+    first = re.search(r"operand_layout_constraints=\{(\w+)\[(\d+),128\]",
+                      kernel[0])
+    assert first and first.group(1) == "bf16"
+    assert int(first.group(2)) == (p.query_block if rows is None
+                                   else max(16, rows))
