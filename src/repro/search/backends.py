@@ -9,7 +9,9 @@ an additive per-row bias (metric bias + tombstone mask), all returning the
   * ``pallas_search`` — fused Pallas PartialReduce kernel (interpret mode on
     CPU, compiled on TPU); cosine works here too since it is biased MIPS.
   * ``make_sharded_search_fn`` — shard_map over a database axis with
-    ``reduction_input_size_override`` recall accounting (paper §7).
+    ``reduction_input_size_override`` recall accounting (paper §7); each
+    shard runs the fused Pallas kernel over its block of rows on a TPU
+    mesh (``shard_scan``), the XLA scan elsewhere.
 
 ``TRACE_COUNTS`` increments once per *trace* of each backend (the body of a
 jitted function only runs while tracing), which is how the compile-cache
@@ -28,7 +30,7 @@ from __future__ import annotations
 
 import collections
 import functools
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +46,7 @@ from repro.kernels.partial_reduce import (
     scan_operands,
 )
 from repro.search.metrics import get_metric
-from repro.search.plan import _plan_query_block
+from repro.search.plan import _plan_query_block, shard_bin_plan
 from repro.search import telemetry
 from repro.search.stages import (
     EXACT,
@@ -66,7 +68,9 @@ __all__ = [
     "DISPATCH_COUNTS",
     "FUSED_SELECT_COUNTS",
     "SCAN_OPERAND_COUNTS",
+    "SHARD_SCAN_COUNTS",
     "CompileCache",
+    "ShardKernel",
     "count_dispatch",
     "cluster_search",
     "cluster_search_quant",
@@ -80,6 +84,7 @@ __all__ = [
     "normalize_db_axes",
     "db_shard_count",
     "default_backend",
+    "shard_scan",
     "reset_trace_counts",
     "reset_dispatch_counts",
 ]
@@ -106,6 +111,11 @@ FUSED_SELECT_COUNTS = telemetry.AtomicCounter()
 # Pallas scan with its dot given that form (``count_dispatch``).
 SCAN_OPERAND_COUNTS = telemetry.AtomicCounter()
 
+# per-shard scan ("pallas": the fused kernel over the shard's block of the
+# kernel layout; "xla": the XLA scan, or the cluster-pruned gather) ->
+# dispatches on the sharded backend (``count_dispatch``).
+SHARD_SCAN_COUNTS = telemetry.AtomicCounter()
+
 telemetry.registry().register_counter_dict(
     "repro_traces_total", TRACE_COUNTS, "backend",
     "jit traces per backend (steady state: zero growth)",
@@ -122,6 +132,10 @@ telemetry.registry().register_counter_dict(
     "repro_scan_operand_dispatches_total", SCAN_OPERAND_COUNTS, "operand",
     "dispatches that run the Pallas scan, by the operand form of its dot",
 )
+telemetry.registry().register_counter_dict(
+    "repro_shard_scan_dispatches_total", SHARD_SCAN_COUNTS, "scan",
+    "sharded dispatches, by the scan each shard runs",
+)
 
 
 def count_dispatch(backend: str, spec, pk, q_dtype) -> None:
@@ -129,8 +143,12 @@ def count_dispatch(backend: str, spec, pk, q_dtype) -> None:
     packed state ``pk`` with queries of ``q_dtype``.  When the dispatched
     program runs the Pallas scan (the dense Pallas path), also count the
     operand form its dot is given, and the fused kernel's select mode when
-    ``fused_select`` is on (a rescore implies ``aggregate_to_topk``)."""
+    ``fused_select`` is on (a rescore implies ``aggregate_to_topk``).  A
+    sharded dispatch also counts the scan its shards run."""
     DISPATCH_COUNTS.inc(backend)
+    if backend == "sharded":
+        SHARD_SCAN_COUNTS.inc(
+            "pallas" if pk.block_n and pk.cluster is None else "xla")
     if backend != "pallas" or pk.cluster is not None:
         return
     SCAN_OPERAND_COUNTS.inc(
@@ -158,6 +176,15 @@ def default_backend(mesh: Optional[Mesh] = None) -> str:
     if mesh is not None:
         return "sharded"
     return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
+def shard_scan(mesh: Mesh, interpret: Optional[bool] = None) -> str:
+    """The scan each shard of the sharded backend runs: ``"pallas"`` (the
+    fused kernel) when the mesh's devices are TPUs or interpret mode was
+    asked for, else ``"xla"`` — ``default_backend``'s rule, per shard."""
+    if interpret or mesh.devices.flat[0].platform == "tpu":
+        return "pallas"
+    return "xla"
 
 
 class CompileCache:
@@ -760,6 +787,19 @@ def _map_query_blocks(fn, q, rows: int):
     return tuple(o.reshape(blocks * rows, *o.shape[2:])[:m] for o in outs)
 
 
+class ShardKernel(NamedTuple):
+    """Statics of the fused kernel each shard runs over its block of the
+    sharded kernel layout (``repro.search.packed``): the shard's logical
+    rows and the layout's tiles.  The kernel always runs its fused
+    scan→select (``SearchSpec.fused_select`` is not consulted here)."""
+
+    n_local: int
+    block_m: int
+    block_n: int
+    interpret: bool
+    int4_packed: bool = False
+
+
 def make_sharded_search_fn(
     mesh: Mesh,
     *,
@@ -772,14 +812,27 @@ def make_sharded_search_fn(
     k_scan: Optional[int] = None,
     cluster_probes: Optional[int] = None,
     cluster_target_scan: Optional[float] = None,
+    kernel: Optional[ShardKernel] = None,
+    bin_size: Optional[int] = None,
 ):
     """Build (queries, database, row_bias) -> (values, indices) over a mesh.
 
     database sharded P(db_axis, None); queries replicated over db_axis and
-    optionally sharded over ``batch_axis``; ``row_bias`` sharded P(db_axis).
-    Each shard PartialReduces its rows with recall accounted against the
-    *global* N (``reduction_input_size_override``), the L bin winners are
+    optionally sharded over ``batch_axis``; ``row_bias`` sharded P(db_axis)
+    (or P(None, db_axis), a (1, n) row).  Each shard PartialReduces its
+    rows with recall accounted against the *global* N, its winners are
     all-gathered, and ExactRescoring runs replicated.
+
+    With ``kernel`` the operands are the sharded kernel layout (a block of
+    ``block_n``-aligned rows a shard, its tail masked) and each shard runs
+    the fused Pallas scan→select over its block: the top ``k`` (f32) or
+    ``k_scan`` (quantized tiers, then rescored on the shard) of its bins.
+    Without it each shard runs the XLA scan over its (n/shards, d) rows,
+    in query sub-blocks that bound its (queries, n/shards) score tile,
+    and cuts its bin winners to the top ``k``.  Either way only k winners
+    a shard are gathered.  ``bin_size`` is the widest bin a shard lays
+    (the packed layout's, ``repro.search.plan.shard_bins``), so both scans
+    lay the same bins; ``None`` (no kernel) lays the recall plan's.
 
     ``db_axis`` may be a single mesh axis name or a *tuple* of names: the
     tuple form splits the database rows over the product of those axes
@@ -839,8 +892,10 @@ def make_sharded_search_fn(
         )
         qspec = P(batch_axis, None) if batch_axis else P(None, None)
 
+        # Per-row operands of the kernel layout are (1, n) rows.
+        row_spec = P(None, db_axes) if bias.ndim == 2 else P(db_axes)
         args = [q, database, bias]
-        in_specs = [qspec, P(db_axes, None), P(db_axes)]
+        in_specs = [qspec, P(db_axes, None), row_spec]
         with_scale = scale is not None
         with_rescore = rescore_db is not None
         with_cluster = centroids is not None
@@ -853,7 +908,7 @@ def make_sharded_search_fn(
             )
         if with_scale:
             args.append(scale)
-            in_specs.append(P(db_axes))
+            in_specs.append(row_spec)
         if with_rescore:
             args.extend([rescore_db, rescore_bias])
             in_specs.extend([P(db_axes, None), P(db_axes)])
@@ -868,7 +923,7 @@ def make_sharded_search_fn(
             # Linearized shard id over the (possibly multi-axis) database
             # split — row-major over db_axes, matching tiled all-gather.
             axis_idx = jax.lax.axis_index(db_axes)
-            n_local = db.shape[0]
+            n_local = kernel.n_local if kernel else db.shape[0]
             offset = axis_idx.astype(jnp.int32) * n_local
             rest = list(rest)
             sc = rest.pop(0) if with_scale else None
@@ -886,7 +941,8 @@ def make_sharded_search_fn(
                 owned = valid & (local >= 0) & (local < n_local)
                 lidc = jnp.clip(local, 0, n_local - 1)
                 scores = score_gathered(
-                    q, db[lidc].astype(jnp.float32), b, lidc, owned, sc
+                    pad_queries_to(q, db.shape[1]),
+                    db[lidc].astype(jnp.float32), b, lidc, owned, sc,
                 )
                 s_slots = scores.shape[-1]
                 plan = plan_bins(
@@ -910,21 +966,34 @@ def make_sharded_search_fn(
                         vals > MASK_VALUE * 0.5, exact, MASK_VALUE
                     )
                 # idxs are global user ids already — no offset to add.
+            elif kernel is not None:
+                # Shard-local ids in [0, n_local); masked winners keep -1.
+                vals, idxs = partial_reduce_fused(
+                    q, db, b, sc, k_scan=scan_k, bin_size=bin_size,
+                    block_m=kernel.block_m, block_n=kernel.block_n,
+                    interpret=kernel.interpret,
+                    int4_packed=kernel.int4_packed,
+                )
+                if with_rescore:
+                    vals, idxs = rescore_candidates(
+                        q, vals, idxs, rs_db, rs_bias, k, scan_k,
+                        use_bitonic,
+                    )
+                idxs = jnp.where(idxs >= 0, idxs + offset, -1)
             else:
+                plan = shard_bin_plan(
+                    n_local, scan_k, recall_target, bin_size or n_local,
+                    global_n,
+                )
+
                 def dense_scan(qs):
                     scores = score_rows(qs, db, b, sc)
-                    plan = plan_bins(
-                        n_local, min(scan_k, n_local), recall_target,
-                        reduction_input_size_override=global_n,
-                    )
                     vals, idxs = partial_reduce_with_plan(
                         scores, plan, mode="max"
                     )
                     if with_rescore:
                         # Cut the shard's bin winners to its k_scan best by
-                        # quantized score, then exact-rescore only those —
-                        # the all-gather then carries exact scores (and
-                        # ~k_scan rows per shard instead of L).
+                        # quantized score, then exact-rescore only those.
                         k_cut = min(scan_k, vals.shape[-1])
                         if k_cut < vals.shape[-1]:
                             vals, sel = jax.lax.top_k(vals, k_cut)
@@ -938,6 +1007,11 @@ def make_sharded_search_fn(
                         vals = jnp.where(
                             vals > MASK_VALUE * 0.5, exact, MASK_VALUE
                         )
+                    if k < vals.shape[-1]:
+                        # The shard's k best, as the fused select leaves:
+                        # the merge's top-k of the union is unchanged.
+                        vals, sel = jax.lax.top_k(vals, k)
+                        idxs = jnp.take_along_axis(idxs, sel, axis=-1)
                     return vals, idxs + offset
 
                 # The shard's dense scan materializes a (queries, n_local)
@@ -946,8 +1020,9 @@ def make_sharded_search_fn(
                 vals, idxs = _map_query_blocks(
                     dense_scan, q, _plan_query_block(n_local, "xla")
                 )
-            # The only cross-device traffic of the whole search: O(k_scan)
-            # (value, global id) winners per shard, merged replicated.
+            # The only cross-device traffic of the whole search: the
+            # (value, global id) winners of each shard (k on the dense
+            # scans), merged replicated.
             vals = jax.lax.all_gather(vals, db_axes, axis=-1, tiled=True)
             idxs = jax.lax.all_gather(idxs, db_axes, axis=-1, tiled=True)
             top_v, top_i = merge_topk(vals, idxs, k, use_bitonic=use_bitonic)

@@ -340,7 +340,6 @@ class Index:
         backend: Optional[str] = None,
         device: Optional[str] = None,
         pin_from: Optional[planlib.Plan] = None,
-        db_shards: Optional[int] = None,
     ) -> planlib.Plan:
         """One re-planning entry point for growth/shard/explain.
 
@@ -368,9 +367,7 @@ class Index:
             reduction_input_size_override=spec.reduction_input_size_override,
             storage=spec.storage, rescore=spec.rescore_enabled,
             cluster=spec.cluster,
-            db_shards=(
-                self._num_db_shards() if db_shards is None else db_shards
-            ),
+            db_shards=self._num_db_shards(),
             residency=spec.residency,
             segment_rows=spec.segment_rows,
             **tiles,
@@ -666,6 +663,20 @@ class Index:
             return 1
         return backends.db_shard_count(self._mesh, self._db_axis)
 
+    def _layout_args(self) -> dict:
+        """``pack_state``'s ``shards`` and ``tiled`` for the resolved
+        backend: the sharded backend's shard count, and the kernel layout
+        where the fused kernel scans it — on the Pallas backend, and in
+        each shard where ``backends.shard_scan`` picks it."""
+        backend = self._resolve_backend()
+        if backend != "sharded":
+            return dict(shards=1, tiled=backend == "pallas")
+        return dict(
+            shards=self._num_db_shards(),
+            tiled=backends.shard_scan(self._mesh, self._interpret)
+            == "pallas",
+        )
+
     def pack(self) -> packedlib.PackedState:
         """The device-resident packed operands for the resolved backend.
 
@@ -679,6 +690,7 @@ class Index:
             self._packed = packedlib.pack_state(
                 self._db, self._live, self.metric, self.spec, backend,
                 cluster_plan=self.kernel_plan.cluster,
+                **self._layout_args(),
             )
             self._place_packed()
         return self._packed
@@ -706,10 +718,14 @@ class Index:
         rows = NamedSharding(self._mesh, P(self._db_axis, None))
         per_row = NamedSharding(self._mesh, P(self._db_axis))
         pk = self._packed
+        # The kernel layout's bias and scale are (1, n) rows.
+        kernel_row = NamedSharding(
+            self._mesh, P(None, self._db_axis) if pk.bias.ndim == 2
+            else P(self._db_axis))
         pk.db = jax.device_put(pk.db, rows)
-        pk.bias = jax.device_put(pk.bias, per_row)
+        pk.bias = jax.device_put(pk.bias, kernel_row)
         if pk.scale is not None:
-            pk.scale = jax.device_put(pk.scale, per_row)
+            pk.scale = jax.device_put(pk.scale, kernel_row)
         if pk.rescore_db is not None:
             pk.rescore_db = jax.device_put(pk.rescore_db, rows)
             pk.rescore_bias = jax.device_put(pk.rescore_bias, per_row)
@@ -967,6 +983,7 @@ class Index:
                 if quantized else None,
                 cluster_probes=probes if clustered else None,
                 cluster_target_scan=target_scan if clustered else None,
+                kernel=self._shard_kernel(pk), bin_size=pk.bin_size,
             )
             jitted = jax.jit(searcher)
             qsharding = NamedSharding(mesh, P(batch_axis, None))
@@ -984,6 +1001,18 @@ class Index:
                 return jitted(jax.device_put(q, qsharding), *ops)
             return fn
         raise ValueError(f"unknown backend {backend!r}")
+
+    def _shard_kernel(self, pk) -> Optional[backends.ShardKernel]:
+        """The fused kernel's statics for each shard of a kernel layout;
+        None on the gather layout, whose shards run the XLA scan."""
+        if not pk.block_n:
+            return None
+        return backends.ShardKernel(
+            n_local=pk.n // pk.shards, block_m=self.spec.block_m,
+            block_n=pk.block_n,
+            interpret=self.interpret,
+            int4_packed=self.spec.storage == "int4",
+        )
 
     def _build_stream_fn(self, backend, pk, batch_axis=None):
         """(blocks (B, qb, D), db, bias) -> ((B, qb, k), (B, qb, k)).
@@ -1006,6 +1035,7 @@ class Index:
                 cluster_probes=cplan.probes if clustered else None,
                 cluster_target_scan=cplan.target_scan
                 if clustered else None,
+                kernel=self._shard_kernel(pk), bin_size=pk.bin_size,
             )
             if clustered and spec.storage == "f32":
                 # Same positional-binding hazard as the block fn: the f32
@@ -1086,7 +1116,7 @@ class Index:
                 self._kernel_plan = dataclasses.replace(
                     self._replan(n=new_cap, m=p.m or None,
                                  backend=p.backend, pin_from=p),
-                    source=p.source,
+                    source=p.source, pinned_tiles=p.pinned_tiles,
                 )
             if self._mesh is not None:
                 self._reshard()
@@ -1234,7 +1264,9 @@ class Index:
         a multiple of the shard count; recall accounting against the
         global N is handled by the sharded backend internally.  The
         packed layout — including the metric precompute — is carried over
-        (``relayout``), not rebuilt.
+        (``relayout``), not rebuilt.  Tiles the user did not pin are
+        re-planned for one shard's rows: where the shards run the fused
+        kernel, each scans its own block of that layout.
         """
         if self.spec.residency != "hbm":
             raise ValueError(
@@ -1249,25 +1281,33 @@ class Index:
         if cap > self.capacity:
             db = jnp.pad(db, ((0, cap - self.capacity), (0, 0)))
             live = jnp.pad(live, (0, cap - self.capacity))
-        sharded_plan = None
-        if self._kernel_plan is not None:
-            # Same tiles (the packed layout carries over); re-evaluate the
-            # prediction for the sharded backend and global capacity.
-            p = self._kernel_plan
-            sharded_plan = dataclasses.replace(
-                self._replan(n=cap, m=p.m or None, backend="sharded",
-                             pin_from=p, db_shards=n_shards),
-                source=p.source,
+        spec = self.spec.with_backend("sharded")
+        p = self._kernel_plan
+        if p is not None:
+            # The planner's tiles are re-planned below, the user's kept.
+            spec = dataclasses.replace(
+                spec,
+                block_m=spec.block_m if "block_m" in p.pinned_tiles
+                else None,
+                max_block_n=spec.max_block_n if "block_n" in p.pinned_tiles
+                else None,
             )
         out = Index(
-            self.spec.with_backend("sharded"), db, live,
+            spec, db, live,
             size=self._size, num_live=self._num_live,
             capacity_block=self._capacity_block,
             mesh=mesh, db_axis=db_axis, batch_axis=batch_axis,
-            interpret=self._interpret, kernel_plan=sharded_plan,
+            interpret=self._interpret,
         )
+        if p is not None:
+            out._kernel_plan = dataclasses.replace(
+                out._replan(m=p.m or None, device=p.device),
+                source=p.source, pinned_tiles=p.pinned_tiles,
+            )
+            out.spec = out._kernel_plan.to_spec(spec)
         if self._packed is not None:
-            out._packed = self._packed.relayout("sharded", cap, out.spec)
+            out._packed = self._packed.relayout(
+                "sharded", cap, out.spec, **out._layout_args())
         out._reshard()
         out.pack()
         return out

@@ -48,9 +48,12 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from repro.core.binning import BinPlan, plan_bins, round_up
 from repro.search import cluster as clusterlib
@@ -58,6 +61,7 @@ from repro.search import quant
 from repro.search import telemetry
 from repro.search.backends import MASK_VALUE
 from repro.search.metrics import Metric
+from repro.search.plan import DEFAULT_BLOCK_N, shard_bins
 from repro.search.spec import SearchSpec
 
 __all__ = [
@@ -127,8 +131,14 @@ class PackedState:
       plan: the BinPlan the pallas layout was derived from.  For quantized
         tiers the plan is laid out for the over-fetched scan k
         (``repro.search.quant.scan_k``), not the user's k.
-      bin_size / block_n: pallas kernel tile parameters (block_n == 0 for
-        non-pallas layouts).
+      bin_size / block_n: kernel tile parameters (block_n == 0 for the
+        gather layout: xla, and sharded where the shards scan with XLA).
+      shards: database shards (1 off the sharded backend).  The sharded
+        backend lays its bins per shard (``repro.search.plan.shard_bins``),
+        and its kernel layout holds one block of rows a shard: shard s's
+        n/shards rows at ``[s·rows, s·rows + n/shards)`` of its ``rows`` =
+        ``db.shape[0] // shards`` rows, its own tail masked, so each
+        shard's block meets the tiling contract.
       storage: the ``repro.search.quant`` tier ``db`` is stored in.  For
         ``"int4"`` the pallas layout stores two codes per byte (db shape
         (n_pad, d_pad/2) int8); other backends keep the canonical one
@@ -152,6 +162,7 @@ class PackedState:
     bin_size: int
     block_n: int
     storage: str = "f32"
+    shards: int = 1
     scale: Optional[jnp.ndarray] = None
     rescore_db: Optional[jnp.ndarray] = None
     rescore_bias: Optional[jnp.ndarray] = None
@@ -180,21 +191,42 @@ class PackedState:
         one int8 code per element, so relayout/snapshot consumers never
         see the packed width.
         """
-        if self.storage == "int4" and self.backend == "pallas":
-            return quant.unpack_int4_rows(self.db[: self.n])[:, : self.d]
-        return self.db[: self.n, : self.d]
+        rows = self._unblock(self.db)
+        if self.storage == "int4" and self.block_n:
+            return quant.unpack_int4_rows(rows)[:, : self.d]
+        return rows[:, : self.d]
 
     def bias_row(self) -> jnp.ndarray:
         """The fused bias without layout padding: (n,)."""
-        flat = self.bias[0] if self.bias.ndim == 2 else self.bias
-        return flat[: self.n]
+        return self._unblock(self.bias[0] if self.bias.ndim == 2
+                             else self.bias)
 
     def scale_row(self) -> Optional[jnp.ndarray]:
         """The int8 per-row scale without layout padding: (n,) or None."""
         if self.scale is None:
             return None
-        flat = self.scale[0] if self.scale.ndim == 2 else self.scale
-        return flat[: self.n]
+        return self._unblock(self.scale[0] if self.scale.ndim == 2
+                             else self.scale)
+
+    def _unblock(self, arr: jnp.ndarray) -> jnp.ndarray:
+        """The n logical rows of a per-row array, each shard's padded
+        tail dropped (one block on every layout but the sharded kernel
+        layout)."""
+        if self.shards <= 1 or not self.block_n:
+            return arr[: self.n]
+        blocks = arr.reshape(self.shards, -1, *arr.shape[1:])
+        return blocks[:, : self.n // self.shards].reshape(
+            self.n, *arr.shape[1:])
+
+    def _positions(self, ids: jnp.ndarray) -> jnp.ndarray:
+        """Layout positions of user row ids: shard s's block starts
+        ``s · (n/shards + its tail)`` rows in, on the sharded kernel
+        layout; everywhere else a row sits at its id."""
+        if self.shards <= 1 or not self.block_n:
+            return ids
+        n_local = self.n // self.shards
+        tail = self.db.shape[0] // self.shards - n_local
+        return ids + (ids // n_local) * tail
 
     def exact_rows_bias(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Full-precision prepared rows + fused bias, (n, d) / (n,).
@@ -238,13 +270,14 @@ class PackedState:
     # -- in-place patches (the cheap mutations) -------------------------------
 
     @staticmethod
-    def _patch_row(arr: jnp.ndarray, start: int, values: jnp.ndarray
+    def _patch_row(arr: jnp.ndarray, where, values: jnp.ndarray
                    ) -> jnp.ndarray:
-        """Write a slice into a per-row array in either layout — (n,) for
-        xla/sharded or (1, n_pad) for pallas (bias and scale alike)."""
+        """Write ``values`` at ``where`` (a slice or layout positions) into
+        a per-row array in either layout — (n,) for the gather layout or
+        (1, n_pad) for the kernel layout (bias and scale alike)."""
         if arr.ndim == 2:
-            return arr.at[0, start : start + values.shape[0]].set(values)
-        return arr.at[start : start + values.shape[0]].set(values)
+            return arr.at[0, where].set(values)
+        return arr.at[where].set(values)
 
     def update_rows(self, start: int, rows: jnp.ndarray, metric: Metric):
         """Patch an appended row slice: prepare only the slice, O(r·D).
@@ -271,7 +304,7 @@ class PackedState:
         exact_slice = (
             prepped if self.storage == "f32" else qr.exact_rows
         )
-        if self.storage == "int4" and self.backend == "pallas":
+        if self.storage == "int4" and self.block_n:
             # Canonical codes -> the on-device nibble-packed width: pad
             # lanes to the logical d_pad (2x the stored byte width), then
             # pack two codes per byte.  Same order as the full pack.
@@ -285,11 +318,14 @@ class PackedState:
             prepped = jnp.pad(
                 prepped, ((0, 0), (0, self.db.shape[1] - prepped.shape[1]))
             )
-        self.db = self.db.at[start : start + r].set(prepped)
-        self.bias = self._patch_row(self.bias, start, slice_bias)
+        where = slice(start, start + r)
+        if self.shards > 1 and self.block_n:
+            where = self._positions(jnp.arange(start, start + r))
+        self.db = self.db.at[where].set(prepped)
+        self.bias = self._patch_row(self.bias, where, slice_bias)
         if self.storage != "f32":
             if self.scale is not None:
-                self.scale = self._patch_row(self.scale, start, qr.scale)
+                self.scale = self._patch_row(self.scale, where, qr.scale)
             if self.rescore_db is not None:
                 self.rescore_db = self.rescore_db.at[
                     start : start + r
@@ -312,10 +348,8 @@ class PackedState:
         pass recomputes true scores, so it must carry its own tombstone
         mask or rescoring would resurrect deleted rows.
         """
-        if self.bias.ndim == 2:
-            self.bias = self.bias.at[0, ids].set(MASK_VALUE)
-        else:
-            self.bias = self.bias.at[ids].set(MASK_VALUE)
+        self.bias = self._patch_row(
+            self.bias, self._positions(ids), MASK_VALUE)
         if self.rescore_bias is not None:
             self.rescore_bias = self.rescore_bias.at[ids].set(MASK_VALUE)
         PACK_EVENTS.inc("bias_patched")
@@ -323,7 +357,8 @@ class PackedState:
     # -- layout changes (copy, but never metric re-preparation) ---------------
 
     def relayout(
-        self, backend: str, new_n: int, spec: SearchSpec
+        self, backend: str, new_n: int, spec: SearchSpec,
+        shards: Optional[int] = None, tiled: Optional[bool] = None,
     ) -> "PackedState":
         """Re-layout for a new capacity and/or backend, reusing prepared rows.
 
@@ -331,7 +366,12 @@ class PackedState:
         ``MASK_VALUE``) until ``update_rows`` writes it.  This is what
         capacity growth and ``Index.shard`` use so the packed layout — and
         the metric precompute in it — survives the transition.
+        ``shards`` and ``tiled`` are as for :func:`pack_state`; by default
+        a layout of the same backend keeps its own.
         """
+        if backend == self.backend:
+            shards = self.shards if shards is None else shards
+            tiled = bool(self.block_n) if tiled is None else tiled
         rows = self.rows()
         bias = self.bias_row()
         scale = self.scale_row()
@@ -349,8 +389,8 @@ class PackedState:
                 )
         PACK_EVENTS.inc("relayout")
         out = _layout(
-            backend, rows, bias, new_n, self.d, spec,
-            scale=scale, rescore_db=rescore_db, rescore_bias=rescore_bias,
+            backend, rows, bias, new_n, self.d, spec, shards=shards or 1,
+            tiled=tiled, scale=scale, rescore_db=rescore_db, rescore_bias=rescore_bias,
             compute_dtype=self.compute_dtype,
         )
         # The side tables hold user row ids, which a relayout never
@@ -386,6 +426,69 @@ def scan_k_for(
     return spec.k
 
 
+def _layout_bins(backend: str, n: int, spec: SearchSpec,
+                 shards: int) -> BinPlan:
+    """The bin plan of a layout: the recall plan over its n rows, or on the
+    sharded backend ``plan.shard_bins``'s (bins laid per shard against
+    the global N and capped at the planned tile)."""
+    k_scan = scan_k_for(spec, n)
+    if backend == "sharded":
+        return shard_bins(
+            n, shards, k_scan, spec.recall_target,
+            spec.max_block_n or DEFAULT_BLOCK_N,
+            spec.reduction_input_size_override,
+        )
+    return plan_bins(
+        n, k_scan, spec.recall_target,
+        reduction_input_size_override=spec.reduction_input_size_override,
+    )
+
+
+# Rows of a database block padded per step of ``_pad_rows``: the padded
+# copy of a step is its only temporary (128 MiB at 128 f32 lanes).
+PAD_CHUNK_ROWS = 1 << 18
+
+
+def _pad_rows(x, *, rows: int, width: int, fill):
+    """``x`` (n, ...) as ``rows`` rows, padded with ``fill`` (a 2-D
+    ``x``'s lanes too, to ``width``).  Copied a chunk of rows at a time,
+    the last chunk overlapping the one before, so no whole-size temporary
+    exists: a TPU stores a (n, d < 128) f32 array lane-major, and a
+    one-shot pad first copies all of it into the row-major layout."""
+    n = x.shape[0]
+    wide = (width,) if x.ndim == 2 else ()
+    lanes = [(0, 0), (0, width - x.shape[1])] if x.ndim == 2 else [(0, 0)]
+    out = jnp.full((rows, *wide), fill, x.dtype)
+    chunk = min(PAD_CHUNK_ROWS, n)
+
+    def step(i, out):
+        start = jnp.minimum(i * chunk, n - chunk)
+        part = jax.lax.dynamic_slice_in_dim(x, start, chunk)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.pad(part, lanes, constant_values=fill), start, 0)
+
+    return jax.lax.fori_loop(0, -(-n // chunk), step, out)
+
+
+def _blocks(x, *, shards: int, rows: int, width: int = 0, fill=0.0):
+    """``x``'s rows as ``shards`` blocks of ``rows`` rows: block s holds
+    rows ``[s·n/shards, (s+1)·n/shards)`` then ``fill``, and a 2-D ``x``
+    has its lanes padded to ``width`` (``_pad_rows``): the kernel layout.
+    Where ``x`` is already split into those blocks over devices, each
+    device pads its own (``shard_map``), so none holds more than its
+    share."""
+    pad = functools.partial(_pad_rows, rows=rows, width=width, fill=fill)
+    sharding = getattr(x, "sharding", None)
+    if (shards > 1 and isinstance(sharding, NamedSharding)
+            and sharding.shard_shape(x.shape)[0] == x.shape[0] // shards):
+        return jax.jit(jax.shard_map(
+            pad, mesh=sharding.mesh, in_specs=sharding.spec,
+            out_specs=sharding.spec, check_vma=False,
+        ))(x)
+    return jax.jit(lambda x: jnp.concatenate(
+        [pad(part) for part in jnp.split(x, shards)]))(x)
+
+
 def _layout(
     backend: str,
     rows: jnp.ndarray,
@@ -394,6 +497,8 @@ def _layout(
     d: int,
     spec: SearchSpec,
     *,
+    shards: int = 1,
+    tiled: Optional[bool] = None,
     scale: Optional[jnp.ndarray] = None,
     rescore_db: Optional[jnp.ndarray] = None,
     rescore_bias: Optional[jnp.ndarray] = None,
@@ -401,51 +506,56 @@ def _layout(
 ) -> PackedState:
     """Lay prepared (rows, bias) out in the backend's native shape.
 
-    The rescore tail stays in gather layout — (n, d) rows, (n,) bias —
-    on every backend: the second pass reads O(M·L) candidates by index,
-    never a tiled stream, so it has no kernel layout to satisfy.
+    ``shards`` and ``tiled`` are as for :func:`pack_state`.  The rescore
+    tail stays in gather layout — (n, d) rows, (n,) bias — on every
+    backend: the second pass reads O(M·L) candidates by index, never a
+    tiled stream, so it has no kernel layout to satisfy (a shard's
+    candidate ids are its own rows' offsets, which its share of the
+    (n, d) tail is indexed by).
     """
-    plan = plan_bins(
-        n, scan_k_for(spec, n), spec.recall_target,
-        reduction_input_size_override=spec.reduction_input_size_override,
-    )
-    bin_size = plan.bin_size
-    if backend == "pallas":
-        # Specs built via Index.build are always planner-resolved; direct
-        # pack_state callers may pass an unresolved spec, which gets the
-        # planner's anchor tile (repro.search.plan owns the real model).
-        from repro.search.plan import DEFAULT_BLOCK_N
-
-        max_bn = spec.max_block_n or DEFAULT_BLOCK_N
-        block_n = bin_size * max(1, max_bn // bin_size)
-        n_pad = round_up(max(n, block_n), block_n)
-        if spec.storage == "int4":
-            # Two codes per byte on device: pad the logical lanes to a
-            # 256-multiple so the packed byte width stays a 128-lane
-            # multiple, then nibble-pack (zero pad codes dequantize to 0,
-            # exact for dot products like zero lanes).
-            d_pad = round_up(d, 256)
-            rows = quant.pack_int4_rows(
-                jnp.pad(rows, ((0, n_pad - n), (0, d_pad - d)))
-            )
-        else:
-            d_pad = round_up(d, 128)
-            rows = jnp.pad(rows, ((0, n_pad - n), (0, d_pad - d)))
-        full = jnp.full((n_pad,), MASK_VALUE, jnp.float32).at[:n].set(bias)
-        if scale is not None:
-            # Padded-tail scale is 0: tail scores become 0*dot + MASK.
-            scale = jnp.zeros((n_pad,), jnp.float32).at[:n].set(scale)[None, :]
+    plan = _layout_bins(backend, n, spec, shards)
+    if not (backend == "pallas" if tiled is None else tiled):
         return PackedState(
-            backend=backend, db=rows, bias=full[None, :], n=n, d=d,
-            plan=plan, bin_size=bin_size, block_n=block_n,
-            storage=spec.storage, scale=scale,
+            backend=backend, db=rows, bias=bias, n=n, d=d,
+            plan=plan, bin_size=plan.bin_size, block_n=0,
+            storage=spec.storage, shards=shards, scale=scale,
             rescore_db=rescore_db, rescore_bias=rescore_bias,
             compute_dtype=compute_dtype,
         )
+    # Specs built via Index.build are always planner-resolved; direct
+    # pack_state callers may pass an unresolved spec, which gets the
+    # planner's anchor tile (repro.search.plan owns the real model).
+    bin_size = plan.bin_size
+    block_n = bin_size * max(1, (spec.max_block_n or DEFAULT_BLOCK_N)
+                             // bin_size)
+    rows_pad = round_up(max(n // shards, block_n), block_n)
+    # int4 stores two codes per byte on device: pad the logical lanes to a
+    # 256-multiple so the packed byte width stays a 128-lane multiple, then
+    # nibble-pack (zero pad codes dequantize to 0, exact for dot products
+    # like zero lanes).  A padded-tail scale is 0: tail scores become
+    # 0*dot + MASK.
+    d_pad = round_up(d, 256 if spec.storage == "int4" else 128)
+    if shards == 1:
+        # One block (the Pallas backend, a one-device mesh) is padded in
+        # one shot; a shard's block a chunk at a time (``_blocks``), where
+        # a one-shot pad of a lane-major shard would not fit beside it.
+        rows = jnp.pad(rows, ((0, rows_pad - n), (0, d_pad - d)))
+        full = jnp.full((rows_pad,), MASK_VALUE, jnp.float32).at[:n].set(bias)
+        if scale is not None:
+            scale = jnp.zeros((rows_pad,), jnp.float32).at[:n].set(scale)
+    else:
+        rows = _blocks(rows, shards=shards, rows=rows_pad, width=d_pad)
+        full = _blocks(bias, shards=shards, rows=rows_pad, fill=MASK_VALUE)
+        if scale is not None:
+            scale = _blocks(scale, shards=shards, rows=rows_pad)
+    if spec.storage == "int4":
+        rows = quant.pack_int4_rows(rows)
+    if scale is not None:
+        scale = scale[None, :]
     return PackedState(
-        backend=backend, db=rows, bias=bias, n=n, d=d,
-        plan=plan, bin_size=bin_size, block_n=0,
-        storage=spec.storage, scale=scale,
+        backend=backend, db=rows, bias=full[None, :], n=n, d=d,
+        plan=plan, bin_size=bin_size, block_n=block_n,
+        storage=spec.storage, shards=shards, scale=scale,
         rescore_db=rescore_db, rescore_bias=rescore_bias,
         compute_dtype=compute_dtype,
     )
@@ -458,12 +568,21 @@ def pack_state(
     spec: SearchSpec,
     backend: str,
     cluster_plan: Optional[clusterlib.ClusterPlan] = None,
+    shards: int = 1,
+    tiled: Optional[bool] = None,
 ) -> PackedState:
     """Full pack: dtype cast + metric preparation over all rows + layout.
 
     The only entry point that runs ``Metric.prepare_database`` on the
     whole database — everything after build goes through the incremental
     patches above.
+
+    ``shards``: the database's shard count on the sharded backend, which
+    lays its bins per shard (1 elsewhere).  ``tiled``: the kernel layout
+    (one block of ``block_n``-aligned rows a shard, lanes padded) rather
+    than the gather layout — for the fused kernel, which the Pallas
+    backend always runs and the sharded backend's shards run where
+    ``backends.shard_scan`` picks it; default: on pallas only.
 
     ``cluster_plan``: an *enabled* ``repro.search.cluster.ClusterPlan``
     builds the pruning side tables over the live prepared rows (k-means +
@@ -486,7 +605,8 @@ def pack_state(
         db, metric_bias = metric.prepare_database(db)
         bias = fuse_bias(metric_bias, live, num_rows=n)
         PACK_EVENTS.inc("full_pack")
-        state = _layout(backend, db, bias, n, d, spec)
+        state = _layout(backend, db, bias, n, d, spec, shards=shards,
+                        tiled=tiled)
         _attach_cluster(state, db, bias, live, metric, cluster_plan, spec.k)
         return state
     # Quantized tier: metric-prepare, quantize, fold the bias correction
@@ -501,7 +621,7 @@ def pack_state(
         rescore_bias = fuse_bias(qr.exact_bias, live, num_rows=n)
     PACK_EVENTS.inc("full_pack")
     state = _layout(
-        backend, qr.rows, bias, n, d, spec,
+        backend, qr.rows, bias, n, d, spec, shards=shards, tiled=tiled,
         scale=qr.scale, rescore_db=rescore_db, rescore_bias=rescore_bias,
         compute_dtype=str(db.dtype),
     )
@@ -611,6 +731,7 @@ def snapshot_state(state: PackedState) -> Tuple[dict, dict]:
         "d": state.d,
         "bin_size": state.bin_size,
         "block_n": state.block_n,
+        "shards": state.shards,
         "storage": state.storage,
         "compute_dtype": state.compute_dtype,
         "cluster_rejected_miss": state.cluster_rejected_miss,
@@ -631,10 +752,8 @@ def restore_state(arrays: dict, meta: dict, spec: SearchSpec) -> PackedState:
     results bit-identical to the original replica's.
     """
     n = int(meta["n"])
-    plan = plan_bins(
-        n, scan_k_for(spec, n), spec.recall_target,
-        reduction_input_size_override=spec.reduction_input_size_override,
-    )
+    shards = int(meta.get("shards", 1))
+    plan = _layout_bins(meta["backend"], n, spec, shards)
     if plan.bin_size != meta["bin_size"]:
         raise ValueError(
             f"snapshot bin_size={meta['bin_size']} but this version plans "
@@ -657,6 +776,7 @@ def restore_state(arrays: dict, meta: dict, spec: SearchSpec) -> PackedState:
         bin_size=int(meta["bin_size"]),
         block_n=int(meta["block_n"]),
         storage=meta["storage"],
+        shards=shards,
         scale=None if scale is None else jnp.asarray(scale),
         rescore_db=None if rescore_db is None else jnp.asarray(rescore_db),
         rescore_bias=(

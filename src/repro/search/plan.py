@@ -48,7 +48,7 @@ import os
 import time
 from typing import Dict, Optional, Tuple
 
-from repro.core.binning import BinPlan, plan_bins, round_up
+from repro.core.binning import BinPlan, expected_recall, plan_bins, round_up
 from repro.core.roofline import (
     HARDWARE,
     Hardware,
@@ -72,6 +72,8 @@ __all__ = [
     "plan_buckets",
     "plan_clusters",
     "plan_segments",
+    "shard_bin_plan",
+    "shard_bins",
     "SEGMENT_ALIGN",
     "tune_plan",
     "detect_device",
@@ -207,8 +209,10 @@ class Plan:
     ``i_mem``/``i_cop``, ``attainable_flops`` and the binding ``bottleneck``
     wall (Eq. 6), and ``predicted_s``/``predicted_qps``.  The cost model
     matches the backend that runs: the fused-kernel Eq. 20 traffic model
-    over the padded layout for ``pallas``, the unfused Level-3-BLAS shape
-    over the raw operands for ``xla``/``sharded``.
+    over the padded layout for ``pallas`` and over one shard's block for
+    ``sharded`` (whose bin fields count every shard; a mesh whose shards
+    run the XLA scan over the same bins is priced as the kernel), the
+    unfused Level-3-BLAS shape over the raw operands for ``xla``.
 
     ``source`` records provenance: ``"model"`` (analytic), ``"measure"``
     (refined by :func:`tune_plan`), or ``"user"`` (explicit overrides pinned
@@ -247,6 +251,9 @@ class Plan:
     predicted_s: float
     predicted_qps: float
     source: str = "model"
+    # the tile fields ("block_m", "block_n") the caller pinned; the others
+    # are the planner's, which ``Index.shard`` re-plans for a shard's rows.
+    pinned_tiles: Tuple[str, ...] = ()
     # recall-accounting N override (paper §7); carried so re-plans (growth,
     # shard, explain) keep the same accounting as the packed layout.
     reduction_input_size_override: int = -1
@@ -461,6 +468,103 @@ def _plan_tiles(
     return block_m, g * unit
 
 
+def shard_bin_plan(n_local: int, k_scan: int, recall_target: float,
+                   max_bin: int, accounting_n: int) -> BinPlan:
+    """One shard's bin layout on the sharded backend: the recall plan of
+    its ``n_local`` rows against ``accounting_n`` (paper §7), with bins no
+    wider than ``max_bin`` (rounded down to a power of two).
+
+    The recall plan against the global N lays a shard's bins as wide as
+    ``n / (shards · L)`` rows (2^18 at 12.5M rows a shard), but a kernel
+    tile holds whole bins, so the plan's tile caps them — for the XLA
+    scan in a shard as for the fused kernel, so both scan the same bins.
+    Every row is still scored, and narrower bins only add bins, so
+    E[recall], counted over every shard's bins, only rises.
+    """
+    recall = plan_bins(n_local, min(k_scan, n_local), recall_target,
+                       reduction_input_size_override=accounting_n)
+    log2_bin = int(math.log2(max_bin))
+    if recall.log2_bin_size <= log2_bin:
+        return recall
+    bins = -(-n_local // (1 << log2_bin))
+    return BinPlan(
+        n=n_local, k=recall.k, num_bins=bins, log2_bin_size=log2_bin,
+        padded_n=bins << log2_bin,
+        expected_recall=expected_recall(
+            bins * max(1, accounting_n // n_local), recall.k),
+    )
+
+
+def shard_bins(n: int, shards: int, k_scan: int, recall_target: float,
+               block_n: int, reduction_input_size_override: int = -1
+               ) -> BinPlan:
+    """Bin layout of the sharded backend over ``shards`` blocks of
+    ``n // shards`` rows (:func:`shard_bin_plan`, capped at ``block_n``),
+    with ``num_bins`` and ``padded_n`` counting all shards; ``padded_n``
+    is the kernel layout's, each shard's block rounded to the tile.
+
+    >>> b = shard_bins(50_000_000, 4, 10, 0.95, 4096)
+    >>> b.bin_size, b.num_bins, round(b.expected_recall, 5)
+    (4096, 12208, 0.99926)
+    """
+    n_local = n // shards
+    local = shard_bin_plan(
+        n_local, k_scan, recall_target, block_n,
+        reduction_input_size_override
+        if reduction_input_size_override > 0 else n,
+    )
+    tile = local.bin_size * max(1, block_n // local.bin_size)
+    return dataclasses.replace(
+        local, n=n, num_bins=local.num_bins * shards,
+        padded_n=shards * round_up(max(n_local, tile), tile),
+    )
+
+
+def _plan_shard_tiles(
+    n_local: int,
+    d_pad: int,
+    bin_size: int,
+    m: Optional[int],
+    dtype_bytes: int,
+    hw: Hardware,
+    *,
+    block_m: Optional[int] = None,
+    max_block_n: Optional[int] = None,
+    db_bytes: Optional[float] = None,
+) -> Tuple[int, int]:
+    """Tiles of the fused kernel over one shard's ``n_local`` rows.
+
+    ``bin_size`` is the recall plan's; the layout caps bins at the tile
+    (:func:`shard_bins`), so the tile width is free.  Each candidate
+    width, a power of two from the recall plan's bin down to
+    ``DEFAULT_BLOCK_N``, is sized by :func:`_plan_tiles` with the fused
+    winner buffer charged: narrower bins grow the buffer, wider tiles the
+    score tile.  The candidate that fits VMEM with the largest query tile
+    wins, the widest on a tie (fewest grid steps).
+    """
+    if max_block_n is not None:
+        capped = min(bin_size, 1 << int(math.log2(max_block_n)))
+        return _plan_tiles(
+            n_local, d_pad, capped, m, dtype_bytes, hw, block_m=block_m,
+            max_block_n=max_block_n, db_bytes=db_bytes, fused_n=n_local,
+        )
+    best, best_key = None, None
+    width = max(bin_size, DEFAULT_BLOCK_N)
+    while width >= DEFAULT_BLOCK_N:
+        capped = min(bin_size, width)
+        bm, bn = _plan_tiles(
+            n_local, d_pad, capped, m, dtype_bytes, hw, block_m=block_m,
+            db_bytes=db_bytes, fused_n=n_local,
+        )
+        fits = _vmem_need(bm, bn, d_pad, dtype_bytes, capped, db_bytes,
+                          n_local) <= _vmem_budget(hw)
+        key = (fits, bm, bn)
+        if best_key is None or key > best_key:
+            best, best_key = (bm, bn), key
+        width //= 2
+    return best
+
+
 def _escalate_block_m(
     block_m: int,
     block_n: int,
@@ -654,12 +758,15 @@ def _plan_query_block(n: int, backend: str) -> int:
     """Host-level query tiling: bound the XLA backend's (qb, N) score tile.
 
     The fused Pallas kernel never materializes the full score matrix, so it
-    keeps the full default.  So does the sharded backend: its score tile is
-    ``(qb, n_local)`` *per shard*, and the shard count is unknown at plan
-    time — shrinking against the global N would explode the dispatch count
-    on exactly the large-N meshes sharding exists for.  Only the dense
-    single-device XLA path, which writes the full ``4*qb*n`` score bytes
-    per dispatch, shrinks ``qb`` under ``SCORE_TILE_BUDGET``.
+    keeps the full default.  So does the sharded backend: where its shards
+    run that kernel nothing is materialized, and where they run the XLA
+    scan each shard cuts the block into sub-blocks against its own
+    ``(qb, n_local)`` score tile (``make_sharded_search_fn``) — the shard
+    count is unknown at plan time, and shrinking against the global N
+    would explode the dispatch count on exactly the large-N meshes
+    sharding exists for.  Only the dense single-device XLA path, which
+    writes the full ``4*qb*n`` score bytes per dispatch, shrinks ``qb``
+    under ``SCORE_TILE_BUDGET``.
     """
     if backend != "xla":
         return DEFAULT_QUERY_BLOCK
@@ -836,16 +943,36 @@ def plan_search(
             rescore=rescore_on, segment_rows=segment_rows,
         )
 
-    bins = plan_bins(
-        n, ks, recall_target,
-        reduction_input_size_override=reduction_input_size_override,
-    )
     d_pad = round_up(d, 128)
-    bm, bn = _plan_tiles(
-        n, d_pad, bins.bin_size, m, qbytes, hw,
-        block_m=block_m, max_block_n=max_block_n, db_bytes=sbytes,
-        fused_n=n if backend == "pallas" else 0,
-    )
+    if backend == "sharded":
+        # Each shard runs the fused kernel over its own n/shards rows,
+        # with bins laid against the global N and capped at the tile.
+        n_local = -(-n // db_shards)
+        recall_bin = plan_bins(
+            n_local, min(ks, n_local), recall_target,
+            reduction_input_size_override=(
+                reduction_input_size_override
+                if reduction_input_size_override > 0
+                else n_local * db_shards),
+        ).bin_size
+        bm, bn = _plan_shard_tiles(
+            n_local, d_pad, recall_bin, m, qbytes, hw,
+            block_m=block_m, max_block_n=max_block_n, db_bytes=sbytes,
+        )
+        bins = shard_bins(
+            n_local * db_shards, db_shards, ks, recall_target, bn,
+            reduction_input_size_override,
+        )
+    else:
+        bins = plan_bins(
+            n, ks, recall_target,
+            reduction_input_size_override=reduction_input_size_override,
+        )
+        bm, bn = _plan_tiles(
+            n, d_pad, bins.bin_size, m, qbytes, hw,
+            block_m=block_m, max_block_n=max_block_n, db_bytes=sbytes,
+            fused_n=n if backend == "pallas" else 0,
+        )
     # Host residency materializes a (qb, segment_rows) score tile per
     # wave, not (qb, N) — size the query block against the wave shape.
     qb = query_block or _plan_query_block(
@@ -864,12 +991,17 @@ def plan_search(
         broadcast_norm=False,
     )
     c = cops_per_dot(**flags)
-    if backend == "pallas":
+    # Rows and bins one fused kernel scans: the database, or one shard's
+    # block of the sharded layout (the shards run concurrently, so the
+    # wall is one shard's scan plus the ICI all-gather priced below).
+    shards = db_shards if backend == "sharded" else 1
+    scan_n, scan_bins = bins.padded_n // shards, bins.num_bins // shards
+    if backend in ("pallas", "sharded"):
         # Only the fused kernel consumes block_m; escalate it off the
         # memory wall (Eq. 10/20) and cost the padded kernel layout.
         if block_m is None:
             bm = _escalate_block_m(
-                bm, bn, m_eff, bins.padded_n, d_pad, bins.num_bins, c,
+                bm, bn, m_eff, scan_n, d_pad, scan_bins, c,
                 qbytes, bins.bin_size, hw, db_bytes=sbytes, k_scan=ks,
             )
         # The kernel clamps its query tile to the sublane-rounded batch
@@ -882,25 +1014,15 @@ def plan_search(
         bm_eff = min(bm, max(sublane_q, round_up(max(m_eff, 1), sublane_q)))
         m_pad = round_up(max(m_eff, 1), bm_eff)
         cost = partial_reduce_fused_cost(
-            m_pad, bins.padded_n, d_pad, ks,
+            m_pad, scan_n, d_pad, ks,
             cops_per_dot=c, block_rows=bm_eff, dtype_bytes=qbytes,
             db_bytes=sbytes, block_n=bn,
             bins_per_block=max(1, bn // bins.bin_size),
         )
     else:
-        # The dense xla path (and each sharded shard) runs the *unpadded*
-        # operands unfused — model the program that actually executes.
-        # With db_shards > 1 the shards run concurrently, so the wall is
-        # ONE shard's scan over N/shards rows (bins laid against the
-        # global N, §7) plus the ICI all-gather priced below.
-        n_scan, scan_bins = n, bins.num_bins
-        if backend == "sharded" and db_shards > 1:
-            n_scan = -(-n // db_shards)
-            scan_bins = plan_bins(
-                n_scan, min(ks, n_scan), recall_target,
-                reduction_input_size_override=n,
-            ).num_bins
-        cost = _dense_cost(m_eff, n_scan, d, scan_bins, dbytes, sbytes)
+        # The dense xla path runs the *unpadded* operands unfused — model
+        # the program that actually executes.
+        cost = _dense_cost(m_eff, n, d, bins.num_bins, dbytes, sbytes)
     expected = bins.expected_recall
     if cplan is not None and cplan.enabled:
         # The pruned gathered program replaces the scan cost wholesale,
@@ -910,7 +1032,7 @@ def plan_search(
         cost = _cluster_cost(m_eff, d, bins.num_bins, cplan, dbytes, sbytes)
         expected = cplan.recall_decomposition(ks)["expected_recall"]
     if rescore_on:
-        extra = _rescore_cost(m_eff, bins.num_bins, ks, d)
+        extra = _rescore_cost(m_eff, scan_bins, ks, d)
         cost = KernelCost(
             flops=cost.flops + extra.flops,
             hbm_bytes=cost.hbm_bytes + extra.hbm_bytes,
@@ -920,15 +1042,13 @@ def plan_search(
     predicted_s = cost.flops / att
     ici_bytes = ici_s = 0.0
     if backend == "sharded" and db_shards > 1:
-        # The §7 collective: every shard all-gathers its O(k_scan) (f32
-        # value, int32 global id) winners to every other shard — 8 bytes
-        # per candidate, shards x candidates rows total.  This is the
+        # The §7 collective: every shard all-gathers its k (f32 value,
+        # int32 global id) winners to every other shard — 8 bytes per
+        # candidate, shards x k rows total: the fused select (and the
+        # quantized tiers' exact rescore) leaves k a shard.  This is the
         # ONLY cross-device traffic of a search, which is the whole
         # traffic-contract argument.
-        # Rescore cuts each shard's contribution to k_scan rows; the
-        # plain dense path all-gathers its L bin winners.
-        cand = ks if rescore_on else scan_bins
-        ici_bytes = 8.0 * m_eff * cand * db_shards
+        ici_bytes = 8.0 * m_eff * k * db_shards
         ici_s = ici_bytes / hw.ici_bandwidth
         predicted_s = predicted_s + ici_s
     pinned = all(v is not None for v in (block_m, max_block_n, query_block))
@@ -944,6 +1064,11 @@ def plan_search(
         attainable_flops=att, bottleneck=bottleneck(cost, hw),
         predicted_s=predicted_s, predicted_qps=m_eff / predicted_s,
         source="user" if pinned else "model",
+        pinned_tiles=tuple(
+            name for name, v in (("block_m", block_m),
+                                 ("block_n", max_block_n))
+            if v is not None
+        ),
         reduction_input_size_override=reduction_input_size_override,
         storage=storage, rescore=rescore_on, k_scan=ks, cluster=cplan,
         db_shards=db_shards, ici_bytes=ici_bytes, ici_s=ici_s,
@@ -1077,8 +1202,8 @@ def _tile_candidates(plan: Plan, spec: Optional[SearchSpec] = None) -> list:
     optimum than 2x is the model being wrong, which is a bug to fix in the
     model, not something to tune around.  Only knobs the sweep may
     legitimately move are varied: user-pinned ``spec`` fields stay fixed,
-    and the dense XLA / sharded paths ignore the Pallas tiles, so for them
-    only ``query_block`` varies.
+    and the dense XLA path ignores the Pallas tiles, so for it only
+    ``query_block`` varies.
     """
     sublane = _SUBLANE.get(_dtype_bytes(plan.dtype), 8)
     # the database tile is stored-dtype (int8 tiles at 32 sublanes); the
@@ -1100,7 +1225,7 @@ def _tile_candidates(plan: Plan, spec: Optional[SearchSpec] = None) -> list:
     def clamp_qb(v):
         return max(8, min(8192, round_up(v, 8)))
 
-    pallas = plan.backend == "pallas"
+    pallas = plan.backend in ("pallas", "sharded")
     m_factors = (1, 0.5, 2) if pallas and (
         spec is None or spec.block_m is None) else (1,)
     n_factors = (1, 0.5, 2) if pallas and (

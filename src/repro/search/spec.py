@@ -80,9 +80,10 @@ class SearchSpec:
         round trip).  ``None`` (default) resolves to True on the pallas
         backend whenever selection happens (``aggregate_to_topk`` or an
         enabled rescore); False pins the two-pass scan→merge path, the
-        bit-identical parity oracle.  Ignored off the pallas backend and
-        by the cluster-pruned front-end (its gathered scan has no
-        streaming j-loop to carry state across).
+        bit-identical parity oracle.  Ignored off the pallas backend —
+        where the sharded backend's shards run the kernel, they always
+        run its fused select — and by the cluster-pruned front-end (its
+        gathered scan has no streaming j-loop to carry state across).
       reduction_input_size_override: recall-accounting N for sharded inputs
         (paper §7); -1 means "use the operand's own N".
       serve_buckets: ascending micro-batch row counts the concurrent

@@ -85,7 +85,7 @@ class FakeDeviceRunner:
             return None
 
 
-@pytest.fixture
+@pytest.fixture(scope="session")
 def fake_devices():
     return FakeDeviceRunner()
 

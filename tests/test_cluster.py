@@ -279,6 +279,25 @@ def test_sharded_cluster_quant_and_f32_operand_binding(data, storage):
     assert _recall(idxs, ei) >= TARGET - 0.12
 
 
+@pytest.mark.parametrize("storage", ["f32", "int8"])
+def test_sharded_cluster_on_the_kernel_layout_runs_the_xla_gather(
+        data, storage):
+    """Where the shards run the fused kernel (interpret mode here), their
+    rows are the per-shard kernel layout, lane-padded; the pruned branch
+    still gathers them with XLA, and the dispatch counts that scan."""
+    db, q = data
+    mesh = make_mesh((1,), ("model",))
+    index = Index.build(db, metric="l2", k=K, backend="xla", storage=storage,
+                        interpret=True).shard(mesh, db_axis="model")
+    pk = index.pack()
+    assert pk.cluster is not None and pk.block_n and pk.db.shape[1] == 128
+    backends.SHARD_SCAN_COUNTS.clear()
+    _, idxs = index.search(q)
+    assert dict(backends.SHARD_SCAN_COUNTS) == {"xla": 1}
+    _, ei = exact_search(q, db, K, metric="l2")
+    assert _recall(idxs, ei) >= TARGET - 0.12
+
+
 # --- packed add/delete contract ----------------------------------------------
 
 

@@ -16,7 +16,13 @@ import pytest
 from repro.core.binning import round_up
 from repro.core.roofline import HARDWARE
 from repro.search import Index, SearchSpec, plan_search, tune_plan
-from repro.search.plan import Plan, PlanCache, detect_device
+from repro.search.plan import (
+    Plan,
+    PlanCache,
+    _vmem_budget,
+    _vmem_need,
+    detect_device,
+)
 
 LEGACY = dict(block_m=256, max_block_n=1024, query_block=4096)
 
@@ -475,6 +481,70 @@ def test_sharded_query_block_not_shrunk_by_global_n():
     p = plan_search(n=1 << 22, d=64, k=10, backend="sharded",
                     device="tpu_v4")
     assert p.query_block == 4096
+
+
+def test_sharded_plan_runs_the_fused_kernel_in_each_shard():
+    """deep50m-f32 on a four-chip v5e: each shard's 12.5M rows get the
+    one-chip fused-kernel tiles, with the global recall plan's 2^18-row
+    bins capped at the tile and the winner buffer charged to VMEM."""
+    p = plan_search(n=50_000_000, d=96, k=10, m=10_000, metric="l2",
+                    backend="sharded", db_shards=4, device="tpu_v5e")
+    assert p.bin_size <= p.block_n and p.block_n % p.bin_size == 0
+    n_local = 50_000_000 // 4
+    need = _vmem_need(p.block_m, p.block_n, p.d_pad, 4, p.bin_size,
+                      fused_n=n_local)
+    assert need <= _vmem_budget(HARDWARE["tpu_v5e"])
+    assert p.expected_recall >= 0.95
+    assert (p.block_m, p.block_n, p.bin_size) == (128, 4096, 4096)
+    assert p.num_bins == 4 * 3052 and p.query_block == 4096
+    # Priced as the fused kernel over one shard's padded block (2MND at
+    # the padded query rows and lanes), not the dense scan over the
+    # global N; the all-gather carries k winners a shard.
+    rows = round_up(n_local, p.block_n)
+    assert p.flops == 2.0 * round_up(10_000, 128) * rows * 128
+    assert p.ici_bytes == 8.0 * 10_000 * 10 * 4
+
+
+@pytest.mark.parametrize("pins", [{}, {"block_m": 8}, {"max_block_n": 256}])
+def test_shard_keeps_the_pinned_tiles_and_replans_the_rest(pins):
+    """A plan records the tiles its caller pinned (``pinned_tiles``);
+    ``Index.shard`` keeps those and re-plans the others for one shard's
+    rows, as a sharded build plans them (at a 256-row pinned tile the
+    capped bins move block_m 256 -> 512)."""
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("model",))
+    db = jax.random.normal(jax.random.PRNGKey(0), (1 << 16, 96))
+    kw = dict(metric="l2", k=10, recall_target=0.5, cluster="off",
+              device="tpu_v5e", **pins)
+    one = Index.build(db, backend="pallas", **kw)
+    moved = one.shard(mesh, db_axis="model")
+    built = Index.build(db, backend="sharded", **kw).shard(
+        mesh, db_axis="model")
+    want = tuple(tile for tile, field in (("block_m", "block_m"),
+                                          ("block_n", "max_block_n"))
+                 if field in pins)
+    assert one.kernel_plan.pinned_tiles == want
+    assert moved.kernel_plan.pinned_tiles == want
+    tiles = (moved.spec.block_m, moved.spec.max_block_n)
+    assert tiles == (built.spec.block_m, built.spec.max_block_n)
+    for field, value in pins.items():
+        assert getattr(moved.spec, field) == value
+
+
+@pytest.mark.parametrize("n,d,metric,storage,want", [
+    (1_000_000, 128, "l2", "f32", (256, 4096, 4096, 10, 4096)),
+    (1_183_514, 100, "cosine", "int8", (256, 2048, 2048, 20, 4096)),
+    (1_000_000, 128, "l2", "bf16", (256, 2048, 2048, 15, 4096)),
+])
+def test_one_chip_plans_of_the_benchmark_cells_hold(n, d, metric, storage,
+                                                     want):
+    """sift1m-f32, glove1.2m-int8 and sift1m-bf16 on one v5e: block_m,
+    block_n, bin_size, k_scan and query_block as their cells run them."""
+    p = plan_search(n=n, d=d, k=10, m=10_000, metric=metric,
+                    storage=storage, backend="pallas", device="tpu_v5e")
+    assert (p.block_m, p.block_n, p.bin_size, p.k_scan,
+            p.query_block) == want
 
 
 def test_plan_cache_corrupt_file_is_empty(tmp_path):

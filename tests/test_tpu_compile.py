@@ -157,3 +157,82 @@ def test_bf16_tier_gives_the_kernel_bf16_queries(
     assert first and first.group(1) == "bf16"
     assert int(first.group(2)) == (p.query_block if rows is None
                                    else max(16, rows))
+
+
+# deep50m-f32 on a four-chip host: 12.5M rows of 96 f32 lanes a chip.
+DEEP_N, DEEP_D, DEEP_SHARDS = 50_000_000, 96, 4
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    from jax.sharding import Mesh
+
+    return Mesh(topo.devices, ("db",))
+
+
+def test_sharded_search_runs_the_fused_kernel_in_each_shard(
+    four_chips, no_persistent_cache
+):
+    """The sharded search a 10,000-query batch streams (three query blocks)
+    over the per-shard kernel layout at the planner's tiles: one Mosaic
+    kernel, the all-gather of the winners, and each chip's share of the
+    padded rows with no database-sized temporary."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.search import backends
+
+    p = plan_search(n=DEEP_N, d=DEEP_D, k=10, metric="l2",
+                    backend="sharded", db_shards=DEEP_SHARDS,
+                    device="tpu_v5e")
+    rows = p.padded_n // DEEP_SHARDS
+    searcher = backends.make_sharded_search_fn(
+        four_chips, metric="l2", k=10, db_axis="db",
+        kernel=backends.ShardKernel(
+            n_local=DEEP_N // DEEP_SHARDS, block_m=p.block_m,
+            block_n=p.block_n, interpret=False),
+        bin_size=p.bin_size,
+    )
+
+    def shaped(shape, spec):
+        return jax.ShapeDtypeStruct(shape, jnp.float32,
+                                    sharding=NamedSharding(four_chips, spec))
+
+    compiled = jax.jit(lambda blocks, db, bias: jax.lax.map(
+        lambda q: searcher(q, db, bias), blocks)).lower(
+        shaped((3, p.query_block, DEEP_D), P(None, None, None)),
+        shaped((DEEP_SHARDS * rows, p.d_pad), P("db", None)),
+        shaped((1, DEEP_SHARDS * rows), P(None, "db")),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "all-gather" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 1.01 * rows * p.d_pad * 4
+    assert mem.temp_size_in_bytes < 2**26
+
+
+def test_per_shard_relayout_copies_a_chunk_at_a_time(
+    four_chips, no_persistent_cache
+):
+    """Each chip pads its lane-major (12.5M, 96) shard into its block of
+    the kernel layout with one chunk's temporary: a one-shot pad would
+    copy the whole shard row-major first, 6.4 GB more than it holds."""
+    import functools
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.search import packed
+
+    rows, spec = 12_500_992, P("db", None)
+    pad = functools.partial(packed._pad_rows, rows=rows, width=128, fill=0.0)
+    compiled = jax.jit(jax.shard_map(
+        pad, mesh=four_chips, in_specs=spec, out_specs=spec,
+        check_vma=False,
+    )).lower(jax.ShapeDtypeStruct(
+        (DEEP_N, DEEP_D), jnp.float32,
+        sharding=NamedSharding(four_chips, spec),
+    )).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes < 1.01 * rows * 128 * 4
+    # A chunk as sliced and as padded: 0.27 GB, not 6.4 GB.
+    assert mem.temp_size_in_bytes < 3 * packed.PAD_CHUNK_ROWS * 128 * 4
